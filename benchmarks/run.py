@@ -34,6 +34,8 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (continuous_bench, durability_bench,
                             dynamic_workload, hybrid_latency, ingestion,
                             multi_query, obs_overhead, pq_study,

@@ -66,7 +66,7 @@ class _FullResultCache:
                     if eval_predicate_rows(batch, pred).any():
                         affected = True
                         break
-                except Exception:
+                except KeyError:    # the delta lacks the predicate column
                     affected = True
                     break
             if affected or query.is_nn:

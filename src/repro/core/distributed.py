@@ -58,9 +58,6 @@ def make_distributed_topk(mesh: Mesh, k: int, shard_axis: str = "data"):
     are all-gathered and merged — collective payload is O(shards * k),
     never O(n).
     """
-    from jax.experimental.shard_map import shard_map
-
-
     def _shardfn(q, vecs, ids):
         d, idx = local_topk(q, vecs, k)             # local candidates
         # padded slots (k > local rows) carry idx=-1: gather a -1 id so
@@ -75,11 +72,11 @@ def make_distributed_topk(mesh: Mesh, k: int, shard_axis: str = "data"):
         neg, pos = jax.lax.top_k(-flat_d, k)
         return -neg, flat_i[pos]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _shardfn, mesh=mesh,
         in_specs=(P(), P(shard_axis, None), P(shard_axis)),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)
 
 
@@ -88,8 +85,6 @@ def make_distributed_hybrid_score(mesh: Mesh, k: int,
     """Weighted multi-modal scatter-gather: vector + spatial distances
     combined on-shard (Algorithm 1's scoring, dense refinement form),
     then global top-k merge."""
-    from jax.experimental.shard_map import shard_map
-
     def _shardfn(qv, qp, w, vecs, pts, ids, mask):
         qf = qv.astype(jnp.float32)
         vf = vecs.astype(jnp.float32)
@@ -112,12 +107,12 @@ def make_distributed_hybrid_score(mesh: Mesh, k: int,
         neg2, pos = jax.lax.top_k(-all_s, k)
         return -neg2, all_i[pos]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _shardfn, mesh=mesh,
         in_specs=(P(), P(), P(), P(shard_axis, None), P(shard_axis, None),
                   P(shard_axis), P(shard_axis)),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)
 
 

@@ -29,6 +29,7 @@ from repro.core.optimizer import cost as cost_lib
 from repro.core.optimizer.stats import Catalog
 from repro.core.types import BLOCK_ROWS
 from repro.kernels import fused_scan as fs_kernel
+from repro.kernels import ops as kops
 
 # global kill switch for the fused kernel path (benchmarks/tests compare
 # against the staged per-segment fallback by flipping this)
@@ -75,6 +76,18 @@ class Plan:
     _describe_cache: Optional[str] = dataclasses.field(
         default=None, repr=False, compare=False)
 
+    def dispatch_label(self) -> str:
+        """The EXPLAIN summary's dispatch suffix; graph dispatch names
+        the program the backend rule runs it as (``ops.graph_program``)."""
+        if self.graph:
+            return (f" dispatch=graph(R={self.graph_r}, "
+                    f"beam={self.graph_beam}, hops={self.graph_hops}, "
+                    f"program={kops.graph_program()})")
+        if self.quantized:
+            return (f" dispatch=quantized(pq m={self.pq_m}, "
+                    f"refine={self.refine})")
+        return " dispatch=fused" if self.fused else ""
+
     def describe(self) -> str:
         """EXPLAIN: one summary line followed by the operator tree with
         per-operator cost estimates (block-read units).  Rendered once
@@ -85,16 +98,7 @@ class Plan:
         if self._describe_cache is not None:
             return self._describe_cache
         from repro.core.operators import _pred_detail
-        if self.graph:
-            disp = (f" dispatch=graph(R={self.graph_r}, "
-                    f"beam={self.graph_beam}, hops={self.graph_hops})")
-        elif self.quantized:
-            disp = (f" dispatch=quantized(pq m={self.pq_m}, "
-                    f"refine={self.refine})")
-        elif self.fused:
-            disp = " dispatch=fused"
-        else:
-            disp = ""
+        disp = self.dispatch_label()
         if self.subplans:
             head = (f"{self.kind}(conjuncts={len(self.subplans)} "
                     f"ranks={len(self.ranks)} cost={self.cost:.1f}{disp})")

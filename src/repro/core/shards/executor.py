@@ -237,16 +237,7 @@ class ShardedExecutor:
             return cached
         n = self.n_shards
         root = self._fanout_tree(plan)
-        if plan.graph:
-            disp = (f" dispatch=graph(R={plan.graph_r}, "
-                    f"beam={plan.graph_beam}, hops={plan.graph_hops})")
-        elif plan.quantized:
-            disp = (f" dispatch=quantized(pq m={plan.pq_m}, "
-                    f"refine={plan.refine})")
-        elif plan.fused:
-            disp = " dispatch=fused"
-        else:
-            disp = ""
+        disp = plan.dispatch_label()
         head = (f"sharded:{plan.kind}(shards={n} "
                 f"ranks={len(plan.ranks)} cost={plan.cost:.1f}{disp})")
         plan._sharded_describe = head + "\n" + root.explain(1)

@@ -136,12 +136,16 @@ class ViewMaintainer:
                 v.remove_many(pks)
             self.deltas_applied += len(pks)
             return
+        from repro.kernels import ops as kops
+        # a write of an existing pk supersedes its old version: rows whose
+        # new value leaves a view are evicted, not left behind stale
         for v in self.coverage.spatial:
             pts = np.asarray(batch[v.col], np.float32)
-            from repro.kernels import ops as kops
             inside = kops.rect_filter(pts, v.rect)
+            v.remove_many(pks[~inside])
             v.insert_many(pks[inside], pts[inside])
         for v in self.coverage.vector:
+            v.remove_many(pks)
             vecs = np.asarray(batch[v.col], np.float32)
             d2 = ((vecs - v.center[None, :]) ** 2).sum(axis=1)
             r = v.coverage_radius()

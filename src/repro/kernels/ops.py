@@ -1,20 +1,31 @@
 """Jit'd wrappers over the Pallas kernels with numpy-friendly padding.
 
-Every op has two backends selected by ``use_pallas``:
-  * pallas  — the TPU-target kernels, executed in interpret mode on CPU
-              (correctness path; sweeps validated against ref.py);
-  * ref     — jnp oracles from ref.py, jit-compiled (fast on CPU).
-
 The host-side ARCADE engine calls these for all per-segment compute:
-distance scans, PQ ADC, predicate bitmaps, top-k merges.
+distance scans, PQ ADC, predicate bitmaps, fused top-k scans, graph
+search, cross-shard merges.  ``backend()`` is THE rule that picks what
+runs, from the device JAX finds — no user switch:
+
+  * ``pallas``    — on a TPU: every op with a Pallas kernel dispatches it
+                    compiled (``interpret=False``); graph search runs its
+                    jitted XLA twin (``GRAPH_PROGRAM``);
+  * ``interpret`` — on the CPU with ``use_pallas`` (``REPRO_USE_PALLAS=1``
+                    for the CI sweep): the same kernels in interpret mode;
+  * ``ref``       — on the CPU otherwise: the jnp oracles from ref.py,
+                    jit-compiled, and the host-simulated fused kernels
+                    that keep CPU fused and staged results bitwise equal.
+
+Below ``HOST_FLOP_CUTOFF`` an op runs in host numpy instead (a size
+rule, in every backend but ``interpret``); those dispatches are counted
+apart from device launches (``KernelStats.host_dispatches``).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import os
 import threading
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +41,37 @@ from repro.kernels import ref
 from repro.kernels import topk_merge as tk_kernel
 from repro.obs import REGISTRY
 
-# global backend switch (tests flip it); env override for benchmarks
+# CPU-only: run the Pallas kernels in interpret mode instead of the jnp
+# oracles (tests flip it; the CI interpret sweep sets the env var).  On a
+# TPU it has no effect — the compiled kernels are the only device path.
 USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
+
+# what graph dispatch runs on a TPU: the jitted XLA twin of the beam
+# search (``ref.graph_search_topk_ref``).  The Pallas kernel holds the
+# whole packed store in VMEM, so it cannot serve a real store compiled.
+GRAPH_PROGRAM = "xla"
+
+
+@functools.lru_cache(maxsize=None)
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def backend(use_pallas: bool = None) -> str:
+    """THE backend rule (see module docstring): ``pallas`` on a TPU,
+    else ``interpret`` when ``use_pallas`` (default ``USE_PALLAS``),
+    else ``ref``."""
+    if on_tpu():
+        return "pallas"
+    return "interpret" if (USE_PALLAS if use_pallas is None
+                           else use_pallas) else "ref"
+
+
+def graph_program(use_pallas: bool = None) -> str:
+    """Which program graph dispatch runs under ``backend()`` — named in
+    EXPLAIN's ``dispatch=graph(...)``."""
+    mode = backend(use_pallas)
+    return GRAPH_PROGRAM if mode == "pallas" else mode
 
 
 # ---------------------------------------------------------------------------
@@ -49,6 +89,9 @@ class KernelStats:
                      ``HOST_FLOP_CUTOFF`` counts too: at production scale
                      the cutoff vanishes and every dispatch is a device
                      launch, so ratios stay machine-independent.
+    host_dispatches — the subset of ``launches`` that ran in host numpy
+                     (no device program); per-program device launches
+                     are tallied process-wide by ``launches_by_tag()``.
     bytes_to_host  — bytes of results handed back to the host engine
                      (device->host traffic when a device backend is
                      active).  Operand upload is not counted.
@@ -60,19 +103,23 @@ class KernelStats:
     launches: int = 0
     bytes_to_host: int = 0
     shape_misses: int = 0
+    host_dispatches: int = 0
     # high-water marks already published to the metrics registry; the
     # per-dispatch mirror batches (see flush_registry_counters) so the
     # hot path pays an int compare instead of a Counter lock
     reg_launches: int = 0
     reg_bytes: int = 0
     reg_misses: int = 0
+    reg_host: int = 0
 
 
 _tls = threading.local()
 _seen_shapes: set = set()
-# the jit shape cache is process-global while the counters are
-# per-thread; guard membership+insert so concurrent first-seens from a
-# query thread and the flush worker don't corrupt the set
+# device launches per program tag ("fused_scan.pallas", ...), all threads
+_tag_launches: collections.Counter = collections.Counter()
+# the jit shape cache and tag tally are process-global while the
+# counters are per-thread; guard membership+insert so concurrent
+# first-seens from a query thread and the flush worker don't corrupt them
 _seen_lock = threading.Lock()
 
 
@@ -89,6 +136,13 @@ def stats_snapshot() -> Tuple[int, int, int]:
     return (s.launches, s.bytes_to_host, s.shape_misses)
 
 
+def launches_by_tag() -> Dict[str, int]:
+    """Process-wide device launches per program tag (``<op>.<backend>``;
+    host-path dispatches carry no tag and are not included)."""
+    with _seen_lock:
+        return dict(_tag_launches)
+
+
 _reg_counters = None
 _reg_generation = -1
 
@@ -97,13 +151,14 @@ def _registry_counters():
     """Process-wide mirrors of the per-thread counters in the metrics
     registry.  Object refs are cached (re-fetched only when
     ``REGISTRY.reset()`` bumps its generation), so the per-dispatch
-    cost is an int compare plus three ``Counter.inc`` calls."""
+    cost is an int compare plus four ``Counter.inc`` calls."""
     global _reg_counters, _reg_generation
     if _reg_counters is None or _reg_generation != REGISTRY.generation:
         _reg_generation = REGISTRY.generation
         _reg_counters = (REGISTRY.counter("kernels.launches"),
                          REGISTRY.counter("kernels.bytes_to_host"),
-                         REGISTRY.counter("kernels.jit_shape_misses"))
+                         REGISTRY.counter("kernels.jit_shape_misses"),
+                         REGISTRY.counter("kernels.host_dispatches"))
     return _reg_counters
 
 
@@ -116,7 +171,7 @@ def flush_registry_counters() -> None:
     query-batch boundaries (``Executor._observe_query``), keeping the
     registry's Counter lock off the per-dispatch path."""
     s = thread_stats()
-    launches, byts, misses = _registry_counters()
+    launches, byts, misses, host = _registry_counters()
     if s.launches != s.reg_launches:
         launches.inc(s.launches - s.reg_launches)
         s.reg_launches = s.launches
@@ -126,19 +181,26 @@ def flush_registry_counters() -> None:
     if s.shape_misses != s.reg_misses:
         misses.inc(s.shape_misses - s.reg_misses)
         s.reg_misses = s.shape_misses
+    if s.host_dispatches != s.reg_host:
+        host.inc(s.host_dispatches - s.reg_host)
+        s.reg_host = s.host_dispatches
 
 
 def _dispatched(out_bytes: int, tag: str = None, shape: Tuple = ()) -> None:
-    """Record one op dispatch; with a ``tag`` also track the jit shape
-    cache (host-path calls pass no tag — numpy has no shape cache)."""
+    """Record one op dispatch; with a ``tag`` it is a device launch of
+    that program and also tracks the jit shape cache (host-path calls
+    pass no tag — numpy has no shape cache)."""
     s = thread_stats()
     s.launches += 1
     s.bytes_to_host += int(out_bytes)
+    if tag is None:
+        s.host_dispatches += 1
     if s.launches - s.reg_launches >= REG_FLUSH_EVERY:
         flush_registry_counters()
     if tag is not None:
         key = (tag,) + tuple(shape)
         with _seen_lock:
+            _tag_launches[tag] += 1
             fresh = key not in _seen_shapes
             if fresh:
                 _seen_shapes.add(key)
@@ -179,12 +241,13 @@ def _pad_bucket(x: np.ndarray, axis: int, value=0.0,
 def _pad_codes(codes: np.ndarray, block: int,
                bucket: bool = True) -> np.ndarray:
     """THE one place PQ code matrices get padded for device dispatch:
-    int32 cast + row pad to a ``block`` multiple (code 0 in the pad rows
-    — masked or sliced off by every consumer), optionally bucket-padded
-    to a power of two.  Shared by both ``pq_adc_distances`` backends and
-    the fused quantized scan so ``stats_snapshot()`` charges code-block
+    row pad to a ``block`` multiple (code 0 in the pad rows — masked or
+    sliced off by every consumer), optionally bucket-padded to a power
+    of two.  Codes stay uint8 (m bytes/row to upload; the device programs
+    widen them).  Shared by both ``pq_adc_distances`` backends and the
+    fused quantized scan so ``stats_snapshot()`` charges code-block
     padding identically whichever path ran."""
-    cp = _pad_to(codes.astype(np.int32), block, 0)
+    cp = _pad_to(np.asarray(codes, np.uint8), block, 0)
     return _pad_bucket(cp, 0, floor=block) if bucket else cp
 
 
@@ -236,23 +299,24 @@ def _l2_host(q: np.ndarray, x: np.ndarray) -> np.ndarray:
 def l2_distances(q: np.ndarray, x: np.ndarray,
                  use_pallas: bool = None) -> np.ndarray:
     """Squared L2: q (nq, d), x (n, d) -> (nq, n) fp32."""
-    use_pallas = USE_PALLAS if use_pallas is None else use_pallas
+    mode = backend(use_pallas)
     q = np.asarray(q, np.float32)
     x = np.asarray(x, np.float32)
     if len(x) == 0:
         return np.zeros((len(q), 0), np.float32)
-    if not use_pallas and q.shape[0] * x.shape[0] * x.shape[1] \
+    if mode != "interpret" and q.shape[0] * x.shape[0] * x.shape[1] \
             < HOST_FLOP_CUTOFF:
         out = _l2_host(q, x)
         _dispatched(out.nbytes)
         return out
-    if use_pallas:
+    if mode != "ref":
         qp = _pad_to(q, ivf_kernel.BLOCK_Q, 0)
         xp = _pad_bucket(_pad_to(x, ivf_kernel.BLOCK_N, 0, value=1e30),
                          0, value=1e30, floor=ivf_kernel.BLOCK_N)
-        out = np.asarray(ivf_kernel.ivf_scan(jnp.asarray(qp),
-                                             jnp.asarray(xp)))
-        _dispatched(out.nbytes, "ivf_scan.pallas", qp.shape + xp.shape)
+        out = np.asarray(ivf_kernel.ivf_scan(
+            jnp.asarray(qp), jnp.asarray(xp),
+            interpret=mode == "interpret"))
+        _dispatched(out.nbytes, f"ivf_scan.{mode}", qp.shape + xp.shape)
         return out[:len(q), :len(x)]
     qp = _pad_bucket(q, 0, floor=8)
     xp = _pad_bucket(x, 0)
@@ -291,24 +355,25 @@ def pq_adc_distances(q: np.ndarray, codes: np.ndarray,
                      codebooks: np.ndarray,
                      use_pallas: bool = None) -> np.ndarray:
     """q (d,); codes (n, m) uint8; codebooks (m, 256, dsub) -> (n,) fp32."""
-    use_pallas = USE_PALLAS if use_pallas is None else use_pallas
+    mode = backend(use_pallas)
     m, n_codes, dsub = codebooks.shape
     qs = q.reshape(m, dsub)
     # LUT: distance from q's subvector to every codeword
     lut = ((codebooks - qs[:, None, :]) ** 2).sum(axis=2)   # (m, 256)
     if len(codes) == 0:
         return np.zeros((0,), np.float32)
-    if not use_pallas and codes.size < HOST_FLOP_CUTOFF:
+    if mode != "interpret" and codes.size < HOST_FLOP_CUTOFF:
         out = np.take_along_axis(
             lut.T, codes.astype(np.int64), axis=0).sum(axis=1) \
             .astype(np.float32)
         _dispatched(out.nbytes)
         return out
     cp = _pad_codes(codes, pq_kernel.BLOCK_N)
-    if use_pallas:
-        out = np.asarray(pq_kernel.pq_adc(jnp.asarray(cp),
-                                          jnp.asarray(lut, jnp.float32)))
-        _dispatched(out.nbytes, "pq_adc.pallas", cp.shape)
+    if mode != "ref":
+        out = np.asarray(pq_kernel.pq_adc(
+            jnp.asarray(cp), jnp.asarray(lut, jnp.float32),
+            interpret=mode == "interpret"))
+        _dispatched(out.nbytes, f"pq_adc.{mode}", cp.shape)
         return out[:len(codes)]
     out = np.asarray(_jit_pq_ref()(jnp.asarray(cp),
                                    jnp.asarray(lut, jnp.float32)))
@@ -323,22 +388,23 @@ def pq_adc_distances(q: np.ndarray, codes: np.ndarray,
 def range_bitmap(cols: np.ndarray, bounds: np.ndarray,
                  use_pallas: bool = None) -> np.ndarray:
     """cols (n, c) fp32; bounds (c, 2) -> (n,) bool (AND of range preds)."""
-    use_pallas = USE_PALLAS if use_pallas is None else use_pallas
+    mode = backend(use_pallas)
     cols = np.asarray(cols, np.float32)
     bounds = np.asarray(bounds, np.float32)
     if len(cols) == 0:
         return np.zeros((0,), bool)
-    if not use_pallas and cols.size < HOST_FLOP_CUTOFF:
+    if mode != "interpret" and cols.size < HOST_FLOP_CUTOFF:
         out = np.all((cols >= bounds[:, 0][None])
                      & (cols <= bounds[:, 1][None]), axis=1)
         _dispatched(out.nbytes)
         return out
-    if use_pallas:
+    if mode != "ref":
         cp = _pad_bucket(_pad_to(cols, bf_kernel.BLOCK_N, 0, value=np.inf),
                          0, value=np.inf, floor=bf_kernel.BLOCK_N)
-        out = np.asarray(bf_kernel.bitmap_filter(jnp.asarray(cp),
-                                                 jnp.asarray(bounds)))
-        _dispatched(out.nbytes, "bitmap.pallas", cp.shape)
+        out = np.asarray(bf_kernel.bitmap_filter(
+            jnp.asarray(cp), jnp.asarray(bounds),
+            interpret=mode == "interpret"))
+        _dispatched(out.nbytes, f"bitmap.{mode}", cp.shape)
         return out[:len(cols)].astype(bool)
     cp = _pad_bucket(cols, 0, value=np.inf)
     out = np.asarray(_jit_bitmap_ref()(jnp.asarray(cp),
@@ -371,14 +437,15 @@ def fused_scan_topk(q: np.ndarray, x: np.ndarray, mask: np.ndarray,
     keys (< 2^31: the device tie-break key).  Returns (d2 (nq, k) fp32
     squared-L2 ascending, rows (nq, k) int64 row indices into ``x``; -1
     marks slots beyond the query's candidate count).  Ties break by
-    (distance, pk) — the host merge's lexsort comparator.  The
-    non-pallas backend SIMULATES the fused kernel: it reproduces the
+    (distance, pk) — the host merge's lexsort comparator.  The CPU
+    ``ref`` backend SIMULATES the fused kernel: it reproduces the
     staged path's distance arithmetic at this size (numpy expansion
     below ``HOST_FLOP_CUTOFF``, the jit'd scan above) and the host
     merge's (sqrt-distance, pk) comparator exactly, so fused and staged
     results are bitwise equal backend-for-backend; the Pallas kernel
-    compares squared distances (a monotone transform — same rows except
-    where f32 sqrt rounds two distinct squared distances together).
+    (always, on a TPU) compares squared distances (a monotone transform
+    — same rows except where f32 sqrt rounds two distinct squared
+    distances together).
 
     ONE dispatch for the whole batch, whatever the segment or predicate
     count.  Host-side prep: rows are tiled into BLOCK_N blocks; blocks
@@ -388,7 +455,7 @@ def fused_scan_topk(q: np.ndarray, x: np.ndarray, mask: np.ndarray,
     block) occupancy grid lets the kernel skip tiles that survive
     compaction but are empty for this query tile.
     """
-    use_pallas = USE_PALLAS if use_pallas is None else use_pallas
+    mode = backend(use_pallas)
     q = np.asarray(q, np.float32)
     x = np.asarray(x, np.float32)
     mask = np.asarray(mask, bool)
@@ -398,7 +465,7 @@ def fused_scan_topk(q: np.ndarray, x: np.ndarray, mask: np.ndarray,
              np.full((nq, k), -1, np.int64))
     if len(x) == 0 or k == 0 or not mask.any():
         return empty
-    if not use_pallas:
+    if mode == "ref":
         # simulated fused kernel: ONE counted dispatch, with the exact
         # arithmetic the staged path uses at this size (numpy expansion
         # below the FLOP cutoff, the same jit'd scan kernel above it)
@@ -454,11 +521,12 @@ def fused_scan_topk(q: np.ndarray, x: np.ndarray, mask: np.ndarray,
     pk32 = pkk.astype(np.int32)[None, :]
     d2, _, idx = fs_kernel.fused_scan_topk(
         jnp.asarray(qp), jnp.asarray(xk), jnp.asarray(mkq),
-        jnp.asarray(pk32), jnp.asarray(occ))
+        jnp.asarray(pk32), jnp.asarray(occ), k=k,
+        interpret=mode == "interpret")
     d2 = np.asarray(d2)
     idx = np.asarray(idx)
-    _dispatched(d2.nbytes + 2 * idx.nbytes, "fused_scan.pallas",
-                qp.shape + xk.shape)
+    _dispatched(d2.nbytes + 2 * idx.nbytes, f"fused_scan.{mode}",
+                qp.shape + xk.shape + (k,))
     d2, idx = d2[:nq, :k], idx[:nq, :k]
     # map packed block-compacted indices back to rows of the caller's x
     safe = np.minimum(idx, len(keep) * BN - 1)
@@ -546,9 +614,11 @@ def graph_search_topk(q: np.ndarray, x: np.ndarray, neighbors: np.ndarray,
 
     Host-side prep pads rows to a bucketed BLOCK_N multiple (padding
     rows are unreachable: their adjacency is all -1 and no real row
-    points at them) and queries to BLOCK_Q tiles.
+    points at them) and queries to BLOCK_Q tiles.  On a TPU the walk
+    runs as the jitted XLA twin (``graph_program()``), tag
+    ``graph_search.xla``.
     """
-    use_pallas = USE_PALLAS if use_pallas is None else use_pallas
+    mode = backend(use_pallas)
     q = np.asarray(q, np.float32)
     x = np.asarray(x, np.float32)
     nbr = np.asarray(neighbors, np.int32)
@@ -565,7 +635,7 @@ def graph_search_topk(q: np.ndarray, x: np.ndarray, neighbors: np.ndarray,
     if n == 0 or beam == 0 or len(ent) == 0 or not mask.any():
         return empty
     work = nq * (hops * beam * nbr.shape[1] + len(ent)) * x.shape[1]
-    if not use_pallas and work < HOST_FLOP_CUTOFF:
+    if mode != "interpret" and work < HOST_FLOP_CUTOFF:
         return _graph_host(q, x, nbr, ent, mask, pks64, beam, hops)
     BQ, BN = fs_kernel.BLOCK_Q, fs_kernel.BLOCK_N
     sent = int(fs_kernel.SENTINEL)
@@ -582,17 +652,16 @@ def graph_search_topk(q: np.ndarray, x: np.ndarray, neighbors: np.ndarray,
     qp = _pad_to(q, BQ, 0)
     mq = _pad_to(mp, BQ, 0)
     pk32 = pkp.astype(np.int32)[None, :]
-    if use_pallas:
+    if mode == "interpret":
         d2, _, ids, vis = gs_kernel.graph_search_topk(
             jnp.asarray(qp), jnp.asarray(xp), jnp.asarray(nbp),
             jnp.asarray(ep), jnp.asarray(mq), jnp.asarray(pk32),
-            beam, hops)
-        tag = "graph_search.pallas"
+            beam, hops, interpret=True)
     else:
         d2, _, ids, vis = _jit_graph_ref(beam, hops)(
             jnp.asarray(qp), jnp.asarray(xp), jnp.asarray(nbp),
             jnp.asarray(ep), jnp.asarray(mq), jnp.asarray(pk32))
-        tag = "graph_search.ref"
+    tag = f"graph_search.{graph_program(use_pallas)}"
     d2 = np.asarray(d2)[:nq]
     ids = np.asarray(ids)[:nq]
     vis = np.asarray(vis)[:nq]
@@ -645,7 +714,7 @@ def quantized_scan_topk(q: np.ndarray, codes: np.ndarray,
     uint8 code matrix instead of the fp32 column: the device streams
     m bytes/row instead of 4*d.
     """
-    use_pallas = USE_PALLAS if use_pallas is None else use_pallas
+    mode = backend(use_pallas)
     q = np.asarray(q, np.float32)
     mask = np.asarray(mask, bool)
     nq = len(q)
@@ -656,7 +725,7 @@ def quantized_scan_topk(q: np.ndarray, codes: np.ndarray,
         return empty
     lut = adc_lut(q, codebooks)                     # (nq, m, 256)
     m = codes.shape[1]
-    if not use_pallas:
+    if mode == "ref":
         # simulated fused ADC kernel: ONE counted dispatch; same gather
         # arithmetic and (adc, pk) comparator as the device kernel
         adc = np.zeros((nq, len(codes)), np.float32)
@@ -684,7 +753,7 @@ def quantized_scan_topk(q: np.ndarray, codes: np.ndarray,
     if len(keep) == 0:
         return empty
     nb_pad = _bucket(len(keep), floor=1)
-    ck = np.zeros((nb_pad * BN, m), np.int32)
+    ck = np.zeros((nb_pad * BN, m), np.uint8)
     mk = np.zeros((nq, nb_pad * BN), np.uint8)
     pkk = np.full((nb_pad * BN,), int(fs_kernel.SENTINEL), np.int64)
     ck[:len(keep) * BN] = cp.reshape(nb, BN, m)[keep].reshape(-1, m)
@@ -698,11 +767,12 @@ def quantized_scan_topk(q: np.ndarray, codes: np.ndarray,
     pk32 = pkk.astype(np.int32)[None, :]
     adc, _, idx = qs_kernel.quantized_scan_topk(
         jnp.asarray(lutf), jnp.asarray(ck), jnp.asarray(mkq),
-        jnp.asarray(pk32), jnp.asarray(occ))
+        jnp.asarray(pk32), jnp.asarray(occ), k=k,
+        interpret=mode == "interpret")
     adc = np.asarray(adc)
     idx = np.asarray(idx)
-    _dispatched(adc.nbytes + 2 * idx.nbytes, "quantized_scan.pallas",
-                lutf.shape + ck.shape)
+    _dispatched(adc.nbytes + 2 * idx.nbytes, f"quantized_scan.{mode}",
+                lutf.shape + ck.shape + (k,))
     adc, idx = adc[:nq, :k], idx[:nq, :k]
     safe = np.minimum(idx, len(keep) * BN - 1)
     rows = keep[safe // BN] * BN + safe % BN
@@ -728,9 +798,10 @@ def merge_topk_batch(scores: np.ndarray, ids: np.ndarray, k: int,
     count.  The device merge tie-breaks in int32 registers (the same
     bound the fused scan's pk registers impose); ids outside [0, 2^31-1)
     automatically fall back to the exact host merge instead of
-    truncating.  ONE dispatch for the whole batch; only the (nq, k)
-    winners return to the host, never the (nq, s*kk) candidate tensor."""
-    use_pallas = USE_PALLAS if use_pallas is None else use_pallas
+    truncating (counted in ``kernels.merge_host_fallbacks``).  ONE
+    dispatch for the whole batch; only the (nq, k) winners return to the
+    host, never the (nq, s*kk) candidate tensor."""
+    mode = backend(use_pallas)
     scores = np.asarray(scores, np.float32)
     ids64 = np.asarray(ids, np.int64)
     nq, s, kk = scores.shape
@@ -739,23 +810,27 @@ def merge_topk_batch(scores: np.ndarray, ids: np.ndarray, k: int,
     out_i = np.full((nq, k), -1, np.int64)
     if k == 0 or nq == 0:
         return out_d, out_i
-    if use_pallas:
+    tag = f"topk_merge_batch.{mode}"
+    if mode != "ref":
         sentinel = np.iinfo(np.int32).max
         real = ids64[np.isfinite(scores)]
-        if len(real) and (int(real.min()) < 0
-                          or int(real.max()) >= sentinel):
-            # the device tie-break key is int32; ids outside its range
-            # would truncate silently — take the exact host merge instead
-            use_pallas = False
-    if use_pallas:
-        idp = np.where(np.isfinite(scores), ids64, sentinel).astype(np.int32)
-        d, i = tk_kernel.batched_topk_merge(jnp.asarray(scores),
-                                            jnp.asarray(idp), k)
-        d = np.asarray(d)
-        i = np.asarray(i, np.int64)
-        _dispatched(d.nbytes + i.nbytes, "topk_merge_batch.pallas",
-                    scores.shape + (k,))
-        return d, np.where(np.isfinite(d), i, -1)
+        if k > fs_kernel.KMAX or (len(real) and (
+                int(real.min()) < 0 or int(real.max()) >= sentinel)):
+            # the kernel emits one lane row (k <= KMAX) and tie-breaks on
+            # int32 ids; wider k or ids outside that range would truncate
+            # silently — take the exact host merge instead
+            REGISTRY.inc("kernels.merge_host_fallbacks")
+            tag = None
+        else:
+            idp = np.where(np.isfinite(scores), ids64,
+                           sentinel).astype(np.int32)
+            d, i = tk_kernel.batched_topk_merge(
+                jnp.asarray(scores), jnp.asarray(idp), k,
+                interpret=mode == "interpret")
+            d = np.asarray(d)
+            i = np.asarray(i, np.int64)
+            _dispatched(d.nbytes + i.nbytes, tag, scores.shape + (k,))
+            return d, np.where(np.isfinite(d), i, -1)
     flat_d = scores.reshape(nq, -1)
     flat_i = ids64.reshape(nq, -1)
     for qi in range(nq):
@@ -763,25 +838,6 @@ def merge_topk_batch(scores: np.ndarray, ids: np.ndarray, k: int,
         order = order[np.isfinite(flat_d[qi][order])]
         out_d[qi, :len(order)] = flat_d[qi][order]
         out_i[qi, :len(order)] = flat_i[qi][order]
-    _dispatched(out_d.nbytes + out_i.nbytes, "topk_merge_batch.ref",
-                scores.shape + (k,))
+    _dispatched(out_d.nbytes + out_i.nbytes, tag,
+                scores.shape + (k,) if tag else ())
     return out_d, out_i
-
-
-def merge_topk(dists: np.ndarray, ids: np.ndarray, k: int,
-               use_pallas: bool = None) -> Tuple[np.ndarray, np.ndarray]:
-    """Merge S per-segment top-k lists (s, kk) -> global (k,)."""
-    use_pallas = USE_PALLAS if use_pallas is None else use_pallas
-    dists = np.asarray(dists, np.float32)
-    ids = np.asarray(ids, np.int64)
-    k = min(k, dists.size)
-    if k == 0:
-        return np.zeros((0,), np.float32), np.zeros((0,), np.int64)
-    if use_pallas:
-        d, i = tk_kernel.topk_merge(jnp.asarray(dists), jnp.asarray(ids), k)
-        _dispatched(d.nbytes + i.nbytes, "topk_merge.pallas",
-                    dists.shape + (k,))
-        return np.asarray(d), np.asarray(i)
-    d, i = ref.topk_merge_ref(jnp.asarray(dists), jnp.asarray(ids), k)
-    _dispatched(d.nbytes + i.nbytes, "topk_merge.ref", dists.shape + (k,))
-    return np.asarray(d), np.asarray(i)

@@ -15,7 +15,10 @@ def ivf_scan_ref(q: jnp.ndarray, vecs: jnp.ndarray) -> jnp.ndarray:
     v = vecs.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=-1, keepdims=True)          # (nq, 1)
     vn = jnp.sum(v * v, axis=-1)[None, :]                # (1, n)
-    return qn - 2.0 * (q @ v.T) + vn
+    # full f32 products: on a TPU the default matmul precision is one
+    # bf16 pass, which would make the "exact" reference approximate
+    dots = jnp.matmul(q, v.T, precision=jax.lax.Precision.HIGHEST)
+    return qn - 2.0 * dots + vn
 
 
 def pq_adc_ref(codes: jnp.ndarray, lut: jnp.ndarray) -> jnp.ndarray:
@@ -32,16 +35,6 @@ def bitmap_filter_ref(cols: jnp.ndarray, bounds: jnp.ndarray) -> jnp.ndarray:
     hi = bounds[:, 1][None, :]
     ok = (cols >= lo) & (cols <= hi)
     return jnp.all(ok, axis=1)
-
-
-def topk_merge_ref(dists: jnp.ndarray, ids: jnp.ndarray, k: int):
-    """Merge S sorted top-k lists: dists/ids (s, k) -> global (k,), (k,)."""
-    flat_d = dists.reshape(-1)
-    flat_i = ids.reshape(-1)
-    # analysis: allow[parity/raw-score-sort] ties break by flattened
-    # position here, matching the kernel's argmin selection order
-    order = jnp.argsort(flat_d)[:k]
-    return flat_d[order], flat_i[order]
 
 
 def batched_topk_merge_ref(dists: jnp.ndarray, ids: jnp.ndarray, k: int):

@@ -37,8 +37,6 @@ def run(multi_pod: bool, n_per_shard: int = 1 << 16, dim: int = 128,
                                 sharding=vec_sh)
     ids = jax.ShapeDtypeStruct((n_global,), jnp.int64, sharding=id_sh)
 
-    from jax.experimental.shard_map import shard_map
-
     shard_axes = tuple(mesh.axis_names)
 
     def _shardfn(q, v, i):
@@ -52,9 +50,9 @@ def run(multi_pod: bool, n_per_shard: int = 1 << 16, dim: int = 128,
         neg, pos = jax.lax.top_k(-all_d, k)
         return -neg, all_i[pos]
 
-    fn = shard_map(_shardfn, mesh=mesh,
-                   in_specs=(P(), P(shard_axes, None), P(shard_axes)),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(_shardfn, mesh=mesh,
+                       in_specs=(P(), P(shard_axes, None), P(shard_axes)),
+                       out_specs=(P(), P()), check_vma=False)
     lowered = jax.jit(fn).lower(qv, vecs, ids)
     compiled = lowered.compile()
     ma = compiled.memory_analysis()

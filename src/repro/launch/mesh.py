@@ -13,14 +13,9 @@ import numpy as np
 
 
 def compat_make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` across JAX versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist in newer releases; Auto is the
-    default everywhere, so omit the argument when unsupported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

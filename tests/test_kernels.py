@@ -45,19 +45,6 @@ def test_bitmap_filter_kernel_matches_ref(n, c):
                                   np.asarray(want))
 
 
-@pytest.mark.parametrize("s,kk,k", [(4, 16, 8), (8, 32, 10), (16, 8, 16)])
-def test_topk_merge_kernel_matches_ref(s, kk, k):
-    rng = np.random.default_rng(3)
-    d = jnp.asarray(np.sort(rng.normal(size=(s, kk)) ** 2, axis=1),
-                    jnp.float32)
-    ids = jnp.asarray(rng.integers(0, 10**6, (s, kk)), jnp.int64)
-    od, oi = tkm.topk_merge(d, ids, k, interpret=True)
-    wd, wi = ref.topk_merge_ref(d, ids, k)
-    np.testing.assert_allclose(np.asarray(od), np.asarray(wd), rtol=1e-6)
-    # ids may differ on exact ties; distances define correctness
-    assert set(np.asarray(oi).tolist()) == set(np.asarray(wi).tolist())
-
-
 @pytest.mark.parametrize("nq,s,kk,k", [(4, 3, 8, 5), (8, 5, 16, 10),
                                        (2, 2, 4, 8)])
 def test_batched_topk_merge_matches_ref(nq, s, kk, k):
@@ -101,9 +88,3 @@ def test_ops_backends_agree():
         ops.pq_adc_distances(qv, codes, books, use_pallas=True),
         ops.pq_adc_distances(qv, codes, books, use_pallas=False),
         rtol=1e-4, atol=1e-4)
-
-    d = np.sort(rng.normal(size=(5, 9)) ** 2, axis=1).astype(np.float32)
-    ids = rng.integers(0, 10**6, (5, 9))
-    d1, i1 = ops.merge_topk(d, ids, 7, use_pallas=True)
-    d2, i2 = ops.merge_topk(d, ids, 7, use_pallas=False)
-    np.testing.assert_allclose(d1, d2, rtol=1e-6)

@@ -43,6 +43,26 @@ def test_spatial_view_incremental_equals_rebuild():
     assert set(v.rows) == before
 
 
+def test_view_update_moving_row_out_evicts_it():
+    """An upsert whose new version leaves a view's region must drop the
+    row's old version: the subscription equals a re-run of its query."""
+    rng = np.random.default_rng(4)
+    store = _store(rng)
+    eng = ContinuousEngine(store, mode="views", view_budget_bytes=2**22)
+    query = q.HybridQuery(where=[q.GeoWithin("coordinate", (2, 2, 5, 5))])
+    eng.register(q.SyncQuery(query, 1.0))
+    v = [v for v in eng.maintainer.views
+         if isinstance(v, SpatialRangeView)][0]
+    moved = sorted(v.rows)[:3]
+    _, batch = make_batch(rng, len(moved), pk_start=0)
+    batch["coordinate"] = np.full((len(moved), 2), 9.9, np.float32)
+    store.put(moved, batch)
+    assert not set(moved) & set(v.rows)
+    got = sorted(r.pk for r in eng.advance(0.0)[0])
+    want = sorted(r.pk for r in Executor(store).execute(query)[0])
+    assert got == want and not set(moved) & set(got)
+
+
 def test_vector_view_contains_true_topxk():
     rng = np.random.default_rng(1)
     store = _store(rng)
