@@ -60,7 +60,7 @@ def _patch_stripped():
     """Remove the obs hooks from the hot path; returns restore state."""
     saved = (Executor.execute_many, kops._registry_counters)
     Executor.execute_many = Executor._execute_many
-    noop = (_NoopCounter(), _NoopCounter(), _NoopCounter())
+    noop = tuple(_NoopCounter() for _ in kops._registry_counters())
     kops._registry_counters = lambda: noop
     return saved
 

@@ -138,8 +138,9 @@ class Executor:
         if type(self).execute is not Executor.execute:
             return [self.execute(qq, p) for qq, p in zip(queries, given)]
 
-        plans = [p or planner_lib.plan(self.catalog, qq)
-                 for p, qq in zip(given, queries)]
+        with obs_trace.span("planner"):
+            plans = [p or planner_lib.plan(self.catalog, qq)
+                     for p, qq in zip(given, queries)]
 
         if (type(self)._exec_nn is not Executor._exec_nn
                 or type(self)._exec_filter is not Executor._exec_filter):
@@ -185,8 +186,9 @@ class Executor:
             idxs = groups.pop(key)
             if len(idxs) >= MIN_SHARED_SCAN_BATCH:
                 for i in idxs:
-                    plans[i] = planner_lib.plan_shared_scan(
-                        self.catalog, queries[i])
+                    with obs_trace.span("planner"):
+                        plans[i] = planner_lib.plan_shared_scan(
+                            self.catalog, queries[i])
                     groups.setdefault(
                         ("nn", key[1], plans[i].fused,
                          getattr(plans[i], "quantized", False),

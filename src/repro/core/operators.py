@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -477,27 +476,28 @@ def _stat_sums(stats: List[ExecStats]) -> Tuple[float, int, int]:
 def _traced_batches(op: PhysicalOp, ctx: PipelineContext
                     ) -> Iterator[Tuple[Any, np.ndarray]]:
     """Timed drain of a source generator: each ``next()`` window runs
-    only the source's own code (consumers work between yields), so the
-    ``ExecStats`` delta across the windows is exactly what this operator
-    charged.  Nested sources (FilterBitmap over IndexProbe) attribute
-    exclusively via a ctx-level accumulator of inner-drain charges; one
-    completed span is recorded at exhaustion."""
+    only the source's own code (consumers work between yields), so it is
+    one window of the operator's ``Drain`` span (one profiler event, the
+    spans it opens nested under it) and the ``ExecStats`` delta across
+    the windows is exactly what this operator charged.  Nested sources
+    (FilterBitmap over IndexProbe) attribute exclusively via a ctx-level
+    accumulator of inner-drain charges; the span is recorded once, with
+    the windows' summed time, at exhaustion."""
     acc = getattr(ctx, "_drain_acc", None)
     if acc is None:
         acc = ctx._drain_acc = [0.0, 0, 0]
     gen = op._batches(ctx)
-    total = 0.0
+    drain = obs_trace.Drain("operator:" + op.name)
     blocks = 0.0
     rows = nbytes = out_rows = 0
     while True:
         pre = _stat_sums(ctx.stats)
         in0 = (acc[0], acc[1], acc[2])
-        t0 = time.perf_counter()
-        try:
-            item = next(gen)
-        except StopIteration:
-            item = None
-        total += time.perf_counter() - t0
+        with drain:
+            try:
+                item = next(gen)
+            except StopIteration:
+                item = None
         post = _stat_sums(ctx.stats)
         blocks += (post[0] - pre[0]) - (acc[0] - in0[0])
         rows += (post[1] - pre[1]) - (acc[1] - in0[1])
@@ -509,8 +509,8 @@ def _traced_batches(op: PhysicalOp, ctx: PipelineContext
     acc[0] += blocks
     acc[1] += rows
     acc[2] += nbytes
-    obs_trace.record_span("operator:" + op.name, total, rows=rows,
-                          bytes=nbytes, blocks=blocks, out_rows=out_rows)
+    obs_trace.record_span(drain.node, rows=rows, bytes=nbytes,
+                          blocks=blocks, out_rows=out_rows)
 
 
 class SegmentScan(PhysicalOp):

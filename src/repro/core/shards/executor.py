@@ -183,16 +183,17 @@ class ShardedExecutor:
         return ShardedPlan(self._plan_logical(query), self)
 
     def _plan_logical(self, query: q.HybridQuery) -> planner_lib.Plan:
-        plan = planner_lib.plan(self.catalog, query)
-        if plan.kind == "postfilter_nn":
-            # the IVF probe is approximate AND shard-layout-sensitive
-            # (per-segment centroid sets differ between shardings), so a
-            # post-filter probe would break sharded==single parity;
-            # demote to the exact shared-scan shape
-            plan = planner_lib.plan_shared_scan(self.catalog, query)
-            plan.note = (plan.note + "; " if plan.note else "") + \
-                "postfilter demoted under sharding"
-            plan.operator_tree(self.catalog)
+        with obs_trace.span("planner"):
+            plan = planner_lib.plan(self.catalog, query)
+            if plan.kind == "postfilter_nn":
+                # the IVF probe is approximate AND shard-layout-sensitive
+                # (per-segment centroid sets differ between shardings), so
+                # a post-filter probe would break sharded==single parity;
+                # demote to the exact shared-scan shape
+                plan = planner_lib.plan_shared_scan(self.catalog, query)
+                plan.note = (plan.note + "; " if plan.note else "") + \
+                    "postfilter demoted under sharding"
+                plan.operator_tree(self.catalog)
         return plan
 
     def _fanout_tree(self, plan: planner_lib.Plan) -> ops.PhysicalOp:
@@ -317,9 +318,10 @@ class ShardedExecutor:
         for idxs in nra_groups.values():
             if len(idxs) >= MIN_SHARED_SCAN_BATCH:
                 for i in idxs:
-                    logical[i] = planner_lib.plan_shared_scan(
-                        self.catalog, queries[i])
-                    logical[i].operator_tree(self.catalog)
+                    with obs_trace.span("planner"):
+                        logical[i] = planner_lib.plan_shared_scan(
+                            self.catalog, queries[i])
+                        logical[i].operator_tree(self.catalog)
 
         # scatter: every shard executes the whole batch under the SAME
         # logical plans (per-shard executors share this thread, so each

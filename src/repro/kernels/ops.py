@@ -17,6 +17,13 @@ runs, from the device JAX finds — no user switch:
 Below ``HOST_FLOP_CUTOFF`` an op runs in host numpy instead (a size
 rule, in every backend but ``interpret``); those dispatches are counted
 apart from device launches (``KernelStats.host_dispatches``).
+
+Every transfer between host and device goes through ``_to_device`` and
+``_to_host``.  With tracing on, each public op opens one span named by
+the path it takes: ``dispatch:<op>`` on the device path (its self time
+is the host's prep: padding, block compaction, masks) or ``host_op:<op>``
+below the cut-off (the host doing the kernel's work); the transfers open
+``transfer:to_device`` and ``transfer:to_host`` inside it.
 """
 from __future__ import annotations
 
@@ -40,6 +47,7 @@ from repro.kernels import quantized_scan as qs_kernel
 from repro.kernels import ref
 from repro.kernels import topk_merge as tk_kernel
 from repro.obs import REGISTRY
+from repro.obs import trace as obs_trace
 
 # CPU-only: run the Pallas kernels in interpret mode instead of the jnp
 # oracles (tests flip it; the CI interpret sweep sets the env var).  On a
@@ -93,8 +101,11 @@ class KernelStats:
                      (no device program); per-program device launches
                      are tallied process-wide by ``launches_by_tag()``.
     bytes_to_host  — bytes of results handed back to the host engine
-                     (device->host traffic when a device backend is
-                     active).  Operand upload is not counted.
+                     (device->host traffic on the device path; the host
+                     path's results count too).
+    bytes_to_device — bytes of operands uploaded by ``_to_device``, in
+                     every backend (``ref`` and ``interpret`` upload to
+                     the CPU device); the host path adds none.
     shape_misses   — first sighting of a (op, bucketed shape) pair, i.e.
                      jit compile-cache misses caused by ``_bucket``-padded
                      ragged inputs (the shape-cache itself is process-
@@ -104,6 +115,7 @@ class KernelStats:
     bytes_to_host: int = 0
     shape_misses: int = 0
     host_dispatches: int = 0
+    bytes_to_device: int = 0
     # high-water marks already published to the metrics registry; the
     # per-dispatch mirror batches (see flush_registry_counters) so the
     # hot path pays an int compare instead of a Counter lock
@@ -111,6 +123,7 @@ class KernelStats:
     reg_bytes: int = 0
     reg_misses: int = 0
     reg_host: int = 0
+    reg_up: int = 0
 
 
 _tls = threading.local()
@@ -151,14 +164,16 @@ def _registry_counters():
     """Process-wide mirrors of the per-thread counters in the metrics
     registry.  Object refs are cached (re-fetched only when
     ``REGISTRY.reset()`` bumps its generation), so the per-dispatch
-    cost is an int compare plus four ``Counter.inc`` calls."""
+    cost is an int compare; a flush makes at most five ``Counter.inc``
+    calls."""
     global _reg_counters, _reg_generation
     if _reg_counters is None or _reg_generation != REGISTRY.generation:
         _reg_generation = REGISTRY.generation
         _reg_counters = (REGISTRY.counter("kernels.launches"),
                          REGISTRY.counter("kernels.bytes_to_host"),
                          REGISTRY.counter("kernels.jit_shape_misses"),
-                         REGISTRY.counter("kernels.host_dispatches"))
+                         REGISTRY.counter("kernels.host_dispatches"),
+                         REGISTRY.counter("kernels.bytes_to_device"))
     return _reg_counters
 
 
@@ -171,7 +186,7 @@ def flush_registry_counters() -> None:
     query-batch boundaries (``Executor._observe_query``), keeping the
     registry's Counter lock off the per-dispatch path."""
     s = thread_stats()
-    launches, byts, misses, host = _registry_counters()
+    launches, byts, misses, host, up = _registry_counters()
     if s.launches != s.reg_launches:
         launches.inc(s.launches - s.reg_launches)
         s.reg_launches = s.launches
@@ -184,6 +199,9 @@ def flush_registry_counters() -> None:
     if s.host_dispatches != s.reg_host:
         host.inc(s.host_dispatches - s.reg_host)
         s.reg_host = s.host_dispatches
+    if s.bytes_to_device != s.reg_up:
+        up.inc(s.bytes_to_device - s.reg_up)
+        s.reg_up = s.bytes_to_device
 
 
 def _dispatched(out_bytes: int, tag: str = None, shape: Tuple = ()) -> None:
@@ -206,6 +224,28 @@ def _dispatched(out_bytes: int, tag: str = None, shape: Tuple = ()) -> None:
                 _seen_shapes.add(key)
         if fresh:
             s.shape_misses += 1
+
+
+def _to_device(*arrays: np.ndarray) -> Tuple[jax.Array, ...]:
+    """Upload host operands, one ``jnp.asarray`` each, inside one
+    ``transfer:to_device`` span; their ``nbytes`` are added to
+    ``KernelStats.bytes_to_device``.  The span times the host's part of
+    the upload: the runtime may finish the copy after it returns, and the
+    kernel waits for it (that wait lands in ``transfer:to_host``)."""
+    n = sum(a.nbytes for a in arrays)
+    thread_stats().bytes_to_device += n
+    with obs_trace.span("transfer:to_device", bytes=n):
+        return tuple(jnp.asarray(a) for a in arrays)
+
+
+def _to_host(*outs: jax.Array):
+    """Fetch device results, ``np.asarray`` each, inside one
+    ``transfer:to_host`` span.  Dispatch is asynchronous, so the span
+    includes the wait for the kernel that makes the results.  One result
+    comes back bare, several as a tuple."""
+    with obs_trace.span("transfer:to_host"):
+        host = tuple(np.asarray(o) for o in outs)
+    return host if len(host) > 1 else host[0]
 
 
 def _pad_to(x: np.ndarray, mult: int, axis: int, value=0.0) -> np.ndarray:
@@ -306,22 +346,24 @@ def l2_distances(q: np.ndarray, x: np.ndarray,
         return np.zeros((len(q), 0), np.float32)
     if mode != "interpret" and q.shape[0] * x.shape[0] * x.shape[1] \
             < HOST_FLOP_CUTOFF:
-        out = _l2_host(q, x)
-        _dispatched(out.nbytes)
+        with obs_trace.span("host_op:l2_distances"):
+            out = _l2_host(q, x)
+            _dispatched(out.nbytes)
         return out
-    if mode != "ref":
-        qp = _pad_to(q, ivf_kernel.BLOCK_Q, 0)
-        xp = _pad_bucket(_pad_to(x, ivf_kernel.BLOCK_N, 0, value=1e30),
-                         0, value=1e30, floor=ivf_kernel.BLOCK_N)
-        out = np.asarray(ivf_kernel.ivf_scan(
-            jnp.asarray(qp), jnp.asarray(xp),
-            interpret=mode == "interpret"))
-        _dispatched(out.nbytes, f"ivf_scan.{mode}", qp.shape + xp.shape)
-        return out[:len(q), :len(x)]
-    qp = _pad_bucket(q, 0, floor=8)
-    xp = _pad_bucket(x, 0)
-    out = np.asarray(_jit_ivf_ref()(jnp.asarray(qp), jnp.asarray(xp)))
-    _dispatched(out.nbytes, "ivf_scan.ref", qp.shape + xp.shape)
+    with obs_trace.span("dispatch:l2_distances"):
+        if mode != "ref":
+            qp = _pad_to(q, ivf_kernel.BLOCK_Q, 0)
+            xp = _pad_bucket(_pad_to(x, ivf_kernel.BLOCK_N, 0, value=1e30),
+                             0, value=1e30, floor=ivf_kernel.BLOCK_N)
+            out = _to_host(ivf_kernel.ivf_scan(
+                *_to_device(qp, xp), interpret=mode == "interpret"))
+            tag = f"ivf_scan.{mode}"
+        else:
+            qp = _pad_bucket(q, 0, floor=8)
+            xp = _pad_bucket(x, 0)
+            out = _to_host(_jit_ivf_ref()(*_to_device(qp, xp)))
+            tag = "ivf_scan.ref"
+        _dispatched(out.nbytes, tag, qp.shape + xp.shape)
     return out[:len(q), :len(x)]
 
 
@@ -356,28 +398,29 @@ def pq_adc_distances(q: np.ndarray, codes: np.ndarray,
                      use_pallas: bool = None) -> np.ndarray:
     """q (d,); codes (n, m) uint8; codebooks (m, 256, dsub) -> (n,) fp32."""
     mode = backend(use_pallas)
-    m, n_codes, dsub = codebooks.shape
-    qs = q.reshape(m, dsub)
-    # LUT: distance from q's subvector to every codeword
-    lut = ((codebooks - qs[:, None, :]) ** 2).sum(axis=2)   # (m, 256)
     if len(codes) == 0:
         return np.zeros((0,), np.float32)
+    m, n_codes, dsub = codebooks.shape
+    qs = q.reshape(m, dsub)
     if mode != "interpret" and codes.size < HOST_FLOP_CUTOFF:
-        out = np.take_along_axis(
-            lut.T, codes.astype(np.int64), axis=0).sum(axis=1) \
-            .astype(np.float32)
-        _dispatched(out.nbytes)
+        with obs_trace.span("host_op:pq_adc_distances"):
+            lut = ((codebooks - qs[:, None, :]) ** 2).sum(axis=2)
+            out = np.take_along_axis(
+                lut.T, codes.astype(np.int64), axis=0).sum(axis=1) \
+                .astype(np.float32)
+            _dispatched(out.nbytes)
         return out
-    cp = _pad_codes(codes, pq_kernel.BLOCK_N)
-    if mode != "ref":
-        out = np.asarray(pq_kernel.pq_adc(
-            jnp.asarray(cp), jnp.asarray(lut, jnp.float32),
-            interpret=mode == "interpret"))
+    with obs_trace.span("dispatch:pq_adc_distances"):
+        # LUT: distance from q's subvector to every codeword (m, 256)
+        lut = ((codebooks - qs[:, None, :]) ** 2).sum(axis=2) \
+            .astype(np.float32)
+        cp = _pad_codes(codes, pq_kernel.BLOCK_N)
+        if mode != "ref":
+            out = _to_host(pq_kernel.pq_adc(
+                *_to_device(cp, lut), interpret=mode == "interpret"))
+        else:
+            out = _to_host(_jit_pq_ref()(*_to_device(cp, lut)))
         _dispatched(out.nbytes, f"pq_adc.{mode}", cp.shape)
-        return out[:len(codes)]
-    out = np.asarray(_jit_pq_ref()(jnp.asarray(cp),
-                                   jnp.asarray(lut, jnp.float32)))
-    _dispatched(out.nbytes, "pq_adc.ref", cp.shape)
     return out[:len(codes)]
 
 
@@ -394,23 +437,23 @@ def range_bitmap(cols: np.ndarray, bounds: np.ndarray,
     if len(cols) == 0:
         return np.zeros((0,), bool)
     if mode != "interpret" and cols.size < HOST_FLOP_CUTOFF:
-        out = np.all((cols >= bounds[:, 0][None])
-                     & (cols <= bounds[:, 1][None]), axis=1)
-        _dispatched(out.nbytes)
+        with obs_trace.span("host_op:range_bitmap"):
+            out = np.all((cols >= bounds[:, 0][None])
+                         & (cols <= bounds[:, 1][None]), axis=1)
+            _dispatched(out.nbytes)
         return out
-    if mode != "ref":
-        cp = _pad_bucket(_pad_to(cols, bf_kernel.BLOCK_N, 0, value=np.inf),
-                         0, value=np.inf, floor=bf_kernel.BLOCK_N)
-        out = np.asarray(bf_kernel.bitmap_filter(
-            jnp.asarray(cp), jnp.asarray(bounds),
-            interpret=mode == "interpret"))
+    with obs_trace.span("dispatch:range_bitmap"):
+        if mode != "ref":
+            cp = _pad_bucket(
+                _pad_to(cols, bf_kernel.BLOCK_N, 0, value=np.inf),
+                0, value=np.inf, floor=bf_kernel.BLOCK_N)
+            out = _to_host(bf_kernel.bitmap_filter(
+                *_to_device(cp, bounds), interpret=mode == "interpret"))
+        else:
+            cp = _pad_bucket(cols, 0, value=np.inf)
+            out = _to_host(_jit_bitmap_ref()(*_to_device(cp, bounds)))
         _dispatched(out.nbytes, f"bitmap.{mode}", cp.shape)
-        return out[:len(cols)].astype(bool)
-    cp = _pad_bucket(cols, 0, value=np.inf)
-    out = np.asarray(_jit_bitmap_ref()(jnp.asarray(cp),
-                                       jnp.asarray(bounds)))
-    _dispatched(out.nbytes, "bitmap.ref", cp.shape)
-    return out[:len(cols)]
+    return out[:len(cols)].astype(bool, copy=False)
 
 
 def rect_filter(points: np.ndarray, rect,
@@ -466,34 +509,50 @@ def fused_scan_topk(q: np.ndarray, x: np.ndarray, mask: np.ndarray,
     if len(x) == 0 or k == 0 or not mask.any():
         return empty
     if mode == "ref":
-        # simulated fused kernel: ONE counted dispatch, with the exact
-        # arithmetic the staged path uses at this size (numpy expansion
-        # below the FLOP cutoff, the same jit'd scan kernel above it)
-        # and the host merge's (score, pk) comparator — so fused and
-        # staged return bitwise-equal results on matching backends
-        if q.shape[0] * x.shape[0] * x.shape[1] < HOST_FLOP_CUTOFF:
-            d2 = _l2_host(q, x)
-            shape_tag = None
-        else:
-            qp = _pad_bucket(q, 0, floor=8)
-            xp = _pad_bucket(x, 0)
-            d2 = np.asarray(_jit_ivf_ref()(jnp.asarray(qp),
-                                           jnp.asarray(xp)))[:nq, :len(x)]
-            shape_tag = qp.shape + xp.shape
-        s = np.where(mask, np.sqrt(np.maximum(d2, 0),
-                                   dtype=np.float32), np.inf)
-        pks64 = np.asarray(pks, np.int64)
-        out_d = np.full((nq, k), np.inf, np.float32)
-        out_r = np.full((nq, k), -1, np.int64)
-        for qi in range(nq):
-            order = np.lexsort((pks64, s[qi]))[:k]
-            order = order[np.isfinite(s[qi][order])]
-            out_d[qi, :len(order)] = d2[qi][order]
-            out_r[qi, :len(order)] = order
-        _dispatched(out_d.nbytes + out_r.nbytes,
-                    None if shape_tag is None else "fused_scan.ref",
-                    shape_tag or ())
-        return out_d, out_r
+        host = q.shape[0] * x.shape[0] * x.shape[1] < HOST_FLOP_CUTOFF
+        with obs_trace.span("host_op:fused_scan_topk" if host
+                            else "dispatch:fused_scan_topk"):
+            return _fused_scan_ref(q, x, mask, pks, k, host)
+    with obs_trace.span("dispatch:fused_scan_topk"):
+        return _fused_scan_device(q, x, mask, pks, k, mode, empty)
+
+
+def _fused_scan_ref(q, x, mask, pks, k, host: bool):
+    """The ``ref`` backend's simulated fused kernel: ONE counted
+    dispatch, with the exact arithmetic the staged path uses at this size
+    (numpy expansion below the FLOP cutoff — ``host`` — the same jit'd
+    scan kernel above it) and the host merge's (score, pk) comparator —
+    so fused and staged return bitwise-equal results on matching
+    backends."""
+    nq = len(q)
+    if host:
+        d2 = _l2_host(q, x)
+        shape_tag = None
+    else:
+        qp = _pad_bucket(q, 0, floor=8)
+        xp = _pad_bucket(x, 0)
+        d2 = _to_host(_jit_ivf_ref()(*_to_device(qp, xp)))[:nq, :len(x)]
+        shape_tag = qp.shape + xp.shape
+    s = np.where(mask, np.sqrt(np.maximum(d2, 0),
+                               dtype=np.float32), np.inf)
+    pks64 = np.asarray(pks, np.int64)
+    out_d = np.full((nq, k), np.inf, np.float32)
+    out_r = np.full((nq, k), -1, np.int64)
+    for qi in range(nq):
+        order = np.lexsort((pks64, s[qi]))[:k]
+        order = order[np.isfinite(s[qi][order])]
+        out_d[qi, :len(order)] = d2[qi][order]
+        out_r[qi, :len(order)] = order
+    _dispatched(out_d.nbytes + out_r.nbytes,
+                None if shape_tag is None else "fused_scan.ref",
+                shape_tag or ())
+    return out_d, out_r
+
+
+def _fused_scan_device(q, x, mask, pks, k, mode: str, empty):
+    """The fused kernel's host prep, upload, launch and fetch (see
+    ``fused_scan_topk``)."""
+    nq = len(q)
     BQ, BN = fs_kernel.BLOCK_Q, fs_kernel.BLOCK_N
     # pad rows to a block multiple (mask=0 => padding is never selected)
     xp = _pad_to(x, BN, 0)
@@ -520,11 +579,9 @@ def fused_scan_topk(q: np.ndarray, x: np.ndarray, mask: np.ndarray,
         .any(axis=(1, 3)).astype(np.int32)
     pk32 = pkk.astype(np.int32)[None, :]
     d2, _, idx = fs_kernel.fused_scan_topk(
-        jnp.asarray(qp), jnp.asarray(xk), jnp.asarray(mkq),
-        jnp.asarray(pk32), jnp.asarray(occ), k=k,
+        *_to_device(qp, xk, mkq, pk32, occ), k=k,
         interpret=mode == "interpret")
-    d2 = np.asarray(d2)
-    idx = np.asarray(idx)
+    d2, idx = _to_host(d2, idx)
     _dispatched(d2.nbytes + 2 * idx.nbytes, f"fused_scan.{mode}",
                 qp.shape + xk.shape + (k,))
     d2, idx = d2[:nq, :k], idx[:nq, :k]
@@ -636,7 +693,18 @@ def graph_search_topk(q: np.ndarray, x: np.ndarray, neighbors: np.ndarray,
         return empty
     work = nq * (hops * beam * nbr.shape[1] + len(ent)) * x.shape[1]
     if mode != "interpret" and work < HOST_FLOP_CUTOFF:
-        return _graph_host(q, x, nbr, ent, mask, pks64, beam, hops)
+        with obs_trace.span("host_op:graph_search_topk"):
+            return _graph_host(q, x, nbr, ent, mask, pks64, beam, hops)
+    with obs_trace.span("dispatch:graph_search_topk"):
+        return _graph_device(q, x, nbr, ent, mask, pks64, beam, hops,
+                             mode, use_pallas)
+
+
+def _graph_device(q, x, nbr, ent, mask, pks64, beam, hops, mode: str,
+                  use_pallas):
+    """The graph walk's host prep, upload, launch and fetch (see
+    ``graph_search_topk``)."""
+    nq, n = len(q), len(x)
     BQ, BN = fs_kernel.BLOCK_Q, fs_kernel.BLOCK_N
     sent = int(fs_kernel.SENTINEL)
     xp = _pad_bucket(_pad_to(x, BN, 0), 0, floor=BN)
@@ -652,19 +720,14 @@ def graph_search_topk(q: np.ndarray, x: np.ndarray, neighbors: np.ndarray,
     qp = _pad_to(q, BQ, 0)
     mq = _pad_to(mp, BQ, 0)
     pk32 = pkp.astype(np.int32)[None, :]
+    operands = _to_device(qp, xp, nbp, ep, mq, pk32)
     if mode == "interpret":
         d2, _, ids, vis = gs_kernel.graph_search_topk(
-            jnp.asarray(qp), jnp.asarray(xp), jnp.asarray(nbp),
-            jnp.asarray(ep), jnp.asarray(mq), jnp.asarray(pk32),
-            beam, hops, interpret=True)
+            *operands, beam, hops, interpret=True)
     else:
-        d2, _, ids, vis = _jit_graph_ref(beam, hops)(
-            jnp.asarray(qp), jnp.asarray(xp), jnp.asarray(nbp),
-            jnp.asarray(ep), jnp.asarray(mq), jnp.asarray(pk32))
+        d2, _, ids, vis = _jit_graph_ref(beam, hops)(*operands)
     tag = f"graph_search.{graph_program(use_pallas)}"
-    d2 = np.asarray(d2)[:nq]
-    ids = np.asarray(ids)[:nq]
-    vis = np.asarray(vis)[:nq]
+    d2, ids, vis = (a[:nq] for a in _to_host(d2, ids, vis))
     _dispatched(d2.nbytes + ids.nbytes + vis.nbytes, tag,
                 qp.shape + xp.shape + (beam, hops))
     rows = np.where(ids == sent, -1, ids).astype(np.int64)
@@ -723,26 +786,45 @@ def quantized_scan_topk(q: np.ndarray, codes: np.ndarray,
              np.full((nq, k), -1, np.int64))
     if len(codes) == 0 or k == 0 or not mask.any():
         return empty
+    if mode == "ref":
+        with obs_trace.span("host_op:quantized_scan_topk"):
+            return _quantized_scan_ref(q, codes, codebooks, mask, pks, k)
+    with obs_trace.span("dispatch:quantized_scan_topk"):
+        return _quantized_scan_device(q, codes, codebooks, mask, pks, k,
+                                      mode, empty)
+
+
+def _quantized_scan_ref(q, codes, codebooks, mask, pks, k):
+    """The ``ref`` backend's simulated fused ADC kernel: ONE counted
+    (host) dispatch; same gather arithmetic and (adc, pk) comparator as
+    the device kernel."""
+    nq = len(q)
     lut = adc_lut(q, codebooks)                     # (nq, m, 256)
     m = codes.shape[1]
-    if mode == "ref":
-        # simulated fused ADC kernel: ONE counted dispatch; same gather
-        # arithmetic and (adc, pk) comparator as the device kernel
-        adc = np.zeros((nq, len(codes)), np.float32)
-        codes64 = codes.astype(np.int64)
-        for j in range(m):
-            adc += lut[:, j, :][:, codes64[:, j]]
-        s = np.where(mask, adc, np.inf)
-        pks64 = np.asarray(pks, np.int64)
-        out_d = np.full((nq, k), np.inf, np.float32)
-        out_r = np.full((nq, k), -1, np.int64)
-        for qi in range(nq):
-            order = np.lexsort((pks64, s[qi]))[:k]
-            order = order[np.isfinite(s[qi][order])]
-            out_d[qi, :len(order)] = s[qi][order]
-            out_r[qi, :len(order)] = order
-        _dispatched(out_d.nbytes + out_r.nbytes)
-        return out_d, out_r
+    adc = np.zeros((nq, len(codes)), np.float32)
+    codes64 = codes.astype(np.int64)
+    for j in range(m):
+        adc += lut[:, j, :][:, codes64[:, j]]
+    s = np.where(mask, adc, np.inf)
+    pks64 = np.asarray(pks, np.int64)
+    out_d = np.full((nq, k), np.inf, np.float32)
+    out_r = np.full((nq, k), -1, np.int64)
+    for qi in range(nq):
+        order = np.lexsort((pks64, s[qi]))[:k]
+        order = order[np.isfinite(s[qi][order])]
+        out_d[qi, :len(order)] = s[qi][order]
+        out_r[qi, :len(order)] = order
+    _dispatched(out_d.nbytes + out_r.nbytes)
+    return out_d, out_r
+
+
+def _quantized_scan_device(q, codes, codebooks, mask, pks, k, mode: str,
+                           empty):
+    """The fused ADC kernel's host prep, upload, launch and fetch (see
+    ``quantized_scan_topk``)."""
+    nq = len(q)
+    lut = adc_lut(q, codebooks)                     # (nq, m, 256)
+    m = codes.shape[1]
     BQ, BN = fs_kernel.BLOCK_Q, fs_kernel.BLOCK_N
     cp = _pad_codes(codes, BN, bucket=False)
     mp = _pad_to(mask.astype(np.uint8), BN, 1)
@@ -766,11 +848,9 @@ def quantized_scan_topk(q: np.ndarray, codes: np.ndarray,
         .any(axis=(1, 3)).astype(np.int32)
     pk32 = pkk.astype(np.int32)[None, :]
     adc, _, idx = qs_kernel.quantized_scan_topk(
-        jnp.asarray(lutf), jnp.asarray(ck), jnp.asarray(mkq),
-        jnp.asarray(pk32), jnp.asarray(occ), k=k,
+        *_to_device(lutf, ck, mkq, pk32, occ), k=k,
         interpret=mode == "interpret")
-    adc = np.asarray(adc)
-    idx = np.asarray(idx)
+    adc, idx = _to_host(adc, idx)
     _dispatched(adc.nbytes + 2 * idx.nbytes, f"quantized_scan.{mode}",
                 lutf.shape + ck.shape + (k,))
     adc, idx = adc[:nq, :k], idx[:nq, :k]
@@ -822,22 +902,26 @@ def merge_topk_batch(scores: np.ndarray, ids: np.ndarray, k: int,
             REGISTRY.inc("kernels.merge_host_fallbacks")
             tag = None
         else:
-            idp = np.where(np.isfinite(scores), ids64,
-                           sentinel).astype(np.int32)
-            d, i = tk_kernel.batched_topk_merge(
-                jnp.asarray(scores), jnp.asarray(idp), k,
-                interpret=mode == "interpret")
-            d = np.asarray(d)
-            i = np.asarray(i, np.int64)
-            _dispatched(d.nbytes + i.nbytes, tag, scores.shape + (k,))
+            with obs_trace.span("dispatch:merge_topk_batch"):
+                idp = np.where(np.isfinite(scores), ids64,
+                               sentinel).astype(np.int32)
+                d, i = _to_host(*tk_kernel.batched_topk_merge(
+                    *_to_device(scores, idp), k,
+                    interpret=mode == "interpret"))
+                i = i.astype(np.int64)
+                _dispatched(d.nbytes + i.nbytes, tag, scores.shape + (k,))
             return d, np.where(np.isfinite(d), i, -1)
-    flat_d = scores.reshape(nq, -1)
-    flat_i = ids64.reshape(nq, -1)
-    for qi in range(nq):
-        order = np.lexsort((flat_i[qi], flat_d[qi]))[:k]
-        order = order[np.isfinite(flat_d[qi][order])]
-        out_d[qi, :len(order)] = flat_d[qi][order]
-        out_i[qi, :len(order)] = flat_i[qi][order]
-    _dispatched(out_d.nbytes + out_i.nbytes, tag,
-                scores.shape + (k,) if tag else ())
+    # the exact host merge (the ``ref`` backend's oracle, counted as its
+    # launch, or the device path's fallback, counted as host work)
+    with obs_trace.span("dispatch:merge_topk_batch" if tag
+                        else "host_op:merge_topk_batch"):
+        flat_d = scores.reshape(nq, -1)
+        flat_i = ids64.reshape(nq, -1)
+        for qi in range(nq):
+            order = np.lexsort((flat_i[qi], flat_d[qi]))[:k]
+            order = order[np.isfinite(flat_d[qi][order])]
+            out_d[qi, :len(order)] = flat_d[qi][order]
+            out_i[qi, :len(order)] = flat_i[qi][order]
+        _dispatched(out_d.nbytes + out_i.nbytes, tag,
+                    scores.shape + (k,) if tag else ())
     return out_d, out_i

@@ -5,7 +5,7 @@ log, and EXPLAIN ANALYZE (ISSUE 10).
 
     obs.set_tracing(True)            # spans (default off, <=2% when off)
     obs.REGISTRY.snapshot()          # counters / gauges / histograms
-    obs.TRACER.chrome_trace()        # Perfetto-loadable trace JSON
+    obs.TRACER.tree()                # finished span trees, indented
     obs.SLOW_LOG.configure(0.05)     # log queries slower than 50ms
 """
 from .analyze import (Analyzed, actuals_from, make_annotator,  # noqa: F401

@@ -9,9 +9,14 @@ Design constraints (ISSUE 10 tentpole):
   * spans nest by contextvar, so operator spans land under their query
     span on the query thread while a background flush worker's spans
     root independently (contextvars are per-thread by construction);
-  * finished ROOT spans are retained in a bounded deque; exports are
-    Chrome trace-event JSON (load in Perfetto / chrome://tracing) and a
-    human-readable indented tree.
+  * finished ROOT spans are retained in a bounded deque (the roots it
+    drops are counted in ``TRACER.dropped``) and render as an indented
+    tree;
+  * every live span is also a ``jax.profiler.TraceAnnotation`` named
+    ``PROFILE_PREFIX + name``, so a profiler trace stamps the program's
+    spans on the device ops' clock, on the thread that did the work.
+    Spans hold durations only; where a span sits in time is the
+    profiler's to record.
 
 Call sites open spans with ``with span("flush") as sp:`` — the
 with-statement guarantees the span closes on exceptions (machine-checked
@@ -23,25 +28,26 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-import json
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
-_EPOCH = time.perf_counter()
+from jax.profiler import TraceAnnotation
+
+# the one prefix of the program's span names on the profiler's timeline
+PROFILE_PREFIX = "repro."
 
 
 class Span:
-    """One finished (or in-flight) span: name, start offset from the
-    tracer epoch, duration, attributes, children."""
+    """One finished (or in-flight) span: name, duration, attributes,
+    children."""
 
-    __slots__ = ("name", "t0", "dur", "attrs", "children")
+    __slots__ = ("name", "dur", "attrs", "children")
     live = True
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None):
         self.name = name
-        self.t0 = 0.0
         self.dur = 0.0
         self.attrs: Dict[str, Any] = attrs if attrs is not None else {}
         self.children: List["Span"] = []
@@ -105,14 +111,19 @@ _current: contextvars.ContextVar[Optional[Span]] = \
 
 
 class Tracer:
-    """Process-wide retention of finished root spans (bounded)."""
+    """Process-wide retention of finished root spans (bounded).
+    ``dropped`` counts the oldest roots pushed out of the full ring; it
+    is monotonic (``clear`` leaves it)."""
 
     def __init__(self, maxlen: int = 256):
         self._lock = threading.Lock()
         self.roots: deque = deque(maxlen=maxlen)
+        self.dropped = 0
 
     def retain(self, root: Span) -> None:
         with self._lock:
+            if len(self.roots) == self.roots.maxlen:
+                self.dropped += 1
             self.roots.append(root)
 
     def clear(self) -> None:
@@ -122,24 +133,6 @@ class Tracer:
     def snapshot(self) -> List[Span]:
         with self._lock:
             return list(self.roots)
-
-    # ------------------------------------------------------------ exports
-    def chrome_trace(self) -> str:
-        """Chrome trace-event JSON ("X" complete events, microseconds) —
-        loadable in Perfetto / chrome://tracing."""
-        events = []
-        for root in self.snapshot():
-            for sp in root.walk():
-                events.append({
-                    "name": sp.name, "ph": "X", "pid": 0, "tid": 0,
-                    "ts": round(sp.t0 * 1e6, 3),
-                    "dur": round(sp.dur * 1e6, 3),
-                    "args": {k: (v if isinstance(v, (int, float, str, bool))
-                                 else repr(v))
-                             for k, v in sp.attrs.items()},
-                })
-        return json.dumps({"traceEvents": events,
-                           "displayTimeUnit": "ms"})
 
     def tree(self) -> str:
         return "\n".join(root.tree() for root in self.snapshot())
@@ -174,29 +167,61 @@ def force_tracing() -> Iterator[None]:
         _enabled = prev
 
 
-class _SpanCtx:
-    """Live context manager returned by ``span()`` when tracing is on."""
+def _profile_event(name: str, attrs: Dict[str, Any]):
+    """The profiler event of a live span, opened; None while no profiler
+    trace is being taken (then it costs one check).  The attributes given
+    at open ride on the event as its stats."""
+    if not TraceAnnotation.is_enabled():
+        return None
+    mark = TraceAnnotation(PROFILE_PREFIX + name, **attrs)
+    mark.__enter__()
+    return mark
 
-    __slots__ = ("node", "token")
 
-    def __init__(self, name: str, attrs: Dict[str, Any]):
-        self.node = Span(name, attrs)
-        self.token: Optional[contextvars.Token] = None
+def _attach(node: Span) -> None:
+    """Hand a finished span to the open parent, or retain it as a root."""
+    parent = _current.get()
+    if parent is None:
+        TRACER.retain(node)
+    else:
+        parent.children.append(node)
+
+
+class _Interval:
+    """One interval of a span: its node is the current span and its
+    profiler event is open; the interval adds to the node's duration."""
+
+    __slots__ = ("node", "token", "mark", "t0")
+
+    def __init__(self, node: Span):
+        self.node = node
 
     def __enter__(self) -> Span:
         self.token = _current.set(self.node)
-        self.node.t0 = time.perf_counter() - _EPOCH
+        self.mark = _profile_event(self.node.name, self.node.attrs)
+        self.t0 = time.perf_counter()
         return self.node
 
     def __exit__(self, *exc: Any) -> bool:
-        node = self.node
-        node.dur = time.perf_counter() - _EPOCH - node.t0
+        self.node.dur += time.perf_counter() - self.t0
+        if self.mark is not None:
+            self.mark.__exit__(None, None, None)
         _current.reset(self.token)
-        parent = _current.get()
-        if parent is None:
-            TRACER.retain(node)
-        else:
-            parent.children.append(node)
+        return False
+
+
+class _SpanCtx(_Interval):
+    """Live context manager returned by ``span()`` when tracing is on:
+    one interval, then the span joins its parent."""
+
+    __slots__ = ()
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        super().__init__(Span(name, attrs))
+
+    def __exit__(self, *exc: Any) -> bool:
+        super().__exit__(*exc)
+        _attach(self.node)
         return False
 
 
@@ -215,17 +240,24 @@ def current_span() -> Optional[Span]:
     return _current.get()
 
 
-def record_span(name: str, duration: float, **attrs: Any) -> Optional[Span]:
-    """Attach an already-measured span (generator drains accumulate time
-    across ``next()`` windows, then record once at exhaustion)."""
-    if not _enabled:
-        return None
-    node = Span(name, attrs)
-    node.t0 = time.perf_counter() - _EPOCH - duration
-    node.dur = duration
-    parent = _current.get()
-    if parent is None:
-        TRACER.retain(node)
-    else:
-        parent.children.append(node)
+class Drain(_Interval):
+    """A span timed in pieces: a source generator that its consumer
+    drains runs only inside ``next()``, so each ``with drain:`` block is
+    one such window.  Each window is a real interval: it is the span's
+    own profiler event, and spans opened inside it become the span's
+    children.  The span joins its parent once, at ``record_span``, with
+    the windows' summed duration."""
+
+    __slots__ = ()
+
+    def __init__(self, name: str):
+        super().__init__(Span(name))
+
+
+def record_span(node: Span, **attrs: Any) -> Span:
+    """Attach a span timed apart (a ``Drain``'s, after its last window):
+    it keeps only its duration, and joins the span open on this flow, or
+    the retained roots when none is."""
+    node.attrs.update(attrs)
+    _attach(node)
     return node

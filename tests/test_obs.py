@@ -2,13 +2,12 @@
 registry, slow-query log, and EXPLAIN ANALYZE (drift exactness + bitwise
 result parity across every dispatch kind, unsharded and sharded).
 """
-import json
-
 import numpy as np
 import pytest
 
 from benchmarks import tracy
 from repro.core import query as q
+from repro.core import operators as ops_lib
 from repro.core.api import (Column, ColumnType, Database, IndexKind, Range,
                             Schema, VectorRank)
 from repro.core.executor import Executor
@@ -78,29 +77,310 @@ def test_record_span_attaches_to_open_parent():
     set_tracing(True)
     TRACER.clear()
     with span("query") as sp:
-        obs_trace.record_span("operator:Scan", 0.002, rows=7)
+        drain = obs_trace.Drain("operator:Scan")
+        for _ in range(3):
+            with drain as node:
+                assert obs_trace.current_span() is node
+                with span("host_op:l2_distances"):
+                    pass
+        assert obs_trace.current_span() is sp
+        obs_trace.record_span(drain.node, rows=7)
     assert [c.name for c in sp.children] == ["operator:Scan"]
     child = sp.children[0]
     assert child.attrs["rows"] == 7
-    assert child.dur == pytest.approx(0.002)
+    assert [c.name for c in child.children] == ["host_op:l2_distances"] * 3
+    assert child.dur >= sum(c.dur for c in child.children)
+    assert not hasattr(child, "t0")
     # without a parent it lands in the ring buffer
-    obs_trace.record_span("flush", 0.001)
+    obs_trace.record_span(obs_trace.Drain("flush").node)
     assert [r.name for r in TRACER.snapshot()] == ["query", "flush"]
 
 
-def test_chrome_trace_export_and_tree():
+def test_tracer_tree_dump():
     set_tracing(True)
     TRACER.clear()
     with span("query", n=2):
         with span("operator:TopKMerge", k=5):
             pass
-    doc = json.loads(TRACER.chrome_trace())
-    names = [e["name"] for e in doc["traceEvents"]]
-    assert sorted(names) == ["operator:TopKMerge", "query"]
-    for e in doc["traceEvents"]:
-        assert e["ph"] == "X" and e["dur"] >= 0.0
     dump = TRACER.tree()
-    assert "query" in dump and "  operator:TopKMerge" in dump
+    assert dump.startswith("query ") and "{n=2}" in dump
+    assert "\n  operator:TopKMerge " in dump and "{k=5}" in dump
+
+
+def test_tracer_counts_the_roots_it_drops():
+    set_tracing(True)
+    tracer = obs_trace.Tracer(maxlen=4)
+    for i in range(7):
+        tracer.retain(obs_trace.Span(f"r{i}"))
+    assert [r.name for r in tracer.snapshot()] == ["r3", "r4", "r5", "r6"]
+    assert tracer.dropped == 3
+    tracer.clear()
+    assert tracer.dropped == 3 and tracer.snapshot() == []
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _profiled(tmp_path, body):
+    """Run ``body`` under obs tracing and a ``jax.profiler`` trace; the
+    host events of the trace as {line index: [(name, start, end, stats)]}."""
+    import jax
+    from jax.profiler import ProfileData
+    set_tracing(True)
+    TRACER.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+        set_tracing(False)
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    lines = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                    dict(ev.stats)) for ev in line.events
+                   if ev.name.startswith(("repro.", "test."))]
+            if evs:
+                lines[len(lines)] = evs
+    return lines
+
+
+def test_spans_reach_the_profiler_trace_nested_on_their_thread(tmp_path):
+    import threading
+
+    import jax
+
+    def body():
+        def elsewhere():
+            with span("flush"):
+                pass
+        with jax.profiler.TraceAnnotation("test.outer"):
+            with span("query", n=1):
+                with span("operator:RankScore"):
+                    with span("transfer:to_device", bytes=4096):
+                        pass
+            worker = threading.Thread(target=elsewhere)
+            worker.start()
+            worker.join()
+
+    lines = _profiled(tmp_path, body)
+    (mine,) = [evs for evs in lines.values()
+               if any(n == "test.outer" for n, *_ in evs)]
+    ev = {n: (s, e, st) for n, s, e, st in mine}
+    assert obs_trace.PROFILE_PREFIX == "repro."
+    assert set(ev) == {"test.outer", "repro.query", "repro.operator:RankScore",
+                       "repro.transfer:to_device"}
+    chain = ["test.outer", "repro.query", "repro.operator:RankScore",
+             "repro.transfer:to_device"]
+    for outer, inner in zip(chain, chain[1:]):
+        assert ev[outer][0] <= ev[inner][0] <= ev[inner][1] <= ev[outer][1]
+    assert ev["repro.transfer:to_device"][2] == {"bytes": 4096}
+    # the worker's span is on its own thread's line
+    others = [n for evs in lines.values() if evs is not mine
+              for n, *_ in evs]
+    assert others == ["repro.flush"]
+    # the span tree agrees
+    (root, flush) = sorted(TRACER.snapshot(), key=lambda r: r.name != "query")
+    assert ["repro." + sp.name for sp in root.walk()] == chain[1:]
+    assert flush.name == "flush"
+
+
+class _Ctx:
+    """The part of a ``PipelineContext`` a traced drain touches."""
+
+    def __init__(self):
+        self.stats = [ops_lib.ExecStats()]
+
+
+class _Source(ops_lib.PhysicalOp):
+    name = "Source"
+
+    def _batches(self, ctx):
+        for i in range(3):
+            with span("host_op:range_bitmap"):
+                ctx.stats[0].rows_scanned += 10
+            yield i, np.ones((1, 4), bool)
+
+
+def test_a_drained_source_gives_one_profiler_event_per_window(tmp_path):
+    src = _Source([])
+
+    def body():
+        with span("operator:Consumer"):
+            for _ in src.batches(_Ctx()):
+                with span("test_consumer_work"):
+                    sum(range(20000))
+
+    lines = _profiled(tmp_path, body)
+    (evs,) = lines.values()
+    windows = [(s, e) for n, s, e, _ in evs if n == "repro.operator:Source"]
+    work = [(s, e) for n, s, e, _ in evs
+            if n == "repro.test_consumer_work"]
+    inner = [(s, e) for n, s, e, _ in evs
+             if n == "repro.host_op:range_bitmap"]
+    assert len(windows) == 4            # three items, then exhaustion
+    assert len(work) == 3 and len(inner) == 3
+    for a, b in windows:
+        for c, d in work:
+            assert b <= c or d <= a     # no window overlaps the consumer
+    for c, d in inner:                  # the source's own spans lie inside
+        assert any(a <= c and d <= b for a, b in windows)
+    # in memory: one span, the windows' summed time, the source's stats
+    (root,) = TRACER.snapshot()
+    names = [c.name for c in root.children]
+    assert names == ["test_consumer_work"] * 3 + ["operator:Source"]
+    node = root.children[-1]
+    assert node.attrs["rows"] == 30 and node.attrs["out_rows"] == 12
+    assert [c.name for c in node.children] == ["host_op:range_bitmap"] * 3
+    assert node.dur <= sum(b - a for a, b in windows) * 1e-9 + 1e-3
+
+
+def test_tracing_off_makes_no_span_object(tracy_ex, monkeypatch):
+    ex, data = tracy_ex
+    made = []
+    real_init = obs_trace.Span.__init__
+
+    def counting(self, *a, **kw):
+        made.append(a[0])
+        real_init(self, *a, **kw)
+
+    monkeypatch.setattr(obs_trace.Span, "__init__", counting)
+    monkeypatch.setattr(kops, "HOST_FLOP_CUTOFF", 0)   # device paths too
+    data.rng = np.random.default_rng(5)
+    qq = q.HybridQuery(
+        where=q.Range("time", 100.0, 600.0),
+        ranks=[q.VectorRank("embedding", data.query_vec(), 1.0)], k=10)
+    ex.execute(qq)
+    assert made == []
+    set_tracing(True)
+    ex.execute(qq)
+    assert "query" in made and "planner" in made
+    assert any(n.startswith("dispatch:") for n in made)
+
+
+# ---------------------------------------------------------------------------
+# kernel dispatch: spans by path, bytes to the device
+# ---------------------------------------------------------------------------
+
+def _dispatch_spans(call):
+    """Run ``call`` traced; its root spans and the thread's counter
+    deltas (bytes to the device, host dispatches)."""
+    st = kops.thread_stats()
+    up0, host0 = st.bytes_to_device, st.host_dispatches
+    set_tracing(True)
+    TRACER.clear()
+    with span("test"):
+        call()
+    set_tracing(False)
+    (root,) = TRACER.snapshot()
+    return (root.children, st.bytes_to_device - up0,
+            st.host_dispatches - host0)
+
+
+def test_bytes_to_device_match_the_padded_operands():
+    from repro.kernels import fused_scan as fs
+    rng = np.random.default_rng(0)
+    d = 128
+    # l2_distances on the ref backend, above the host cut-off: the query
+    # rows bucket to 8 and the vectors to a power of two >= 128
+    q8 = rng.standard_normal((5, d)).astype(np.float32)
+    x = rng.standard_normal((7000, d)).astype(np.float32)
+    assert 5 * 7000 * d >= kops.HOST_FLOP_CUTOFF
+    spans, up, host = _dispatch_spans(
+        lambda: kops.l2_distances(q8, x, use_pallas=False))
+    assert up == (8 * d + 8192 * d) * 4 and host == 0
+    (sp,) = spans
+    assert sp.name == "dispatch:l2_distances"
+    assert [c.name for c in sp.children] == ["transfer:to_device",
+                                             "transfer:to_host"]
+    assert sp.children[0].attrs == {"bytes": up}
+    # fused_scan_topk on the kernel path: 3 queries over 1,400 rows; the
+    # mask leaves blocks 0 and 2 of 3, compacted and bucketed to 2
+    BQ, BN = fs.BLOCK_Q, fs.BLOCK_N
+    q3 = rng.standard_normal((3, d)).astype(np.float32)
+    xs = rng.standard_normal((1400, d)).astype(np.float32)
+    mask = np.zeros((3, 1400), bool)
+    mask[0, 5:40] = True
+    mask[2, 1100:1300] = True
+    pks = np.arange(1400)
+    spans, up, host = _dispatch_spans(
+        lambda: kops.fused_scan_topk(q3, xs, mask, pks, 10,
+                                     use_pallas=True))
+    rows = 2 * BN
+    qtile = BQ                          # 3 queries pad to one tile
+    assert up == (qtile * d * 4         # queries, f32
+                  + rows * d * 4        # kept blocks of vectors, f32
+                  + qtile * rows        # mask, u8
+                  + rows * 4            # pks, i32
+                  + 1 * 2 * 4)          # occupancy (tiles x blocks), i32
+    assert host == 0
+    (sp,) = spans
+    assert sp.name == "dispatch:fused_scan_topk"
+    assert sp.children[0].attrs == {"bytes": up}
+    # a host-path call uploads nothing
+    spans, up, host = _dispatch_spans(
+        lambda: kops.l2_distances(q3, xs[:50], use_pallas=False))
+    assert up == 0 and host == 1
+    assert [s.name for s in spans] == ["host_op:l2_distances"]
+    assert spans[0].children == []
+
+
+@pytest.mark.parametrize("op,call", [
+    ("l2_distances", lambda: kops.l2_distances(
+        np.ones((2, 16), np.float32), np.ones((300, 16), np.float32),
+        use_pallas=False)),
+    ("range_bitmap", lambda: kops.range_bitmap(
+        np.ones((300, 2), np.float32), np.array([[0, 2], [0, 2]], np.float32),
+        use_pallas=False)),
+    ("pq_adc_distances", lambda: kops.pq_adc_distances(
+        np.ones(8, np.float32), np.zeros((300, 4), np.uint8),
+        np.ones((4, 256, 2), np.float32), use_pallas=False)),
+    ("fused_scan_topk", lambda: kops.fused_scan_topk(
+        np.ones((1, 16), np.float32), np.ones((300, 16), np.float32),
+        np.ones((1, 300), bool), np.arange(300), 4, use_pallas=False)),
+])
+def test_dispatch_span_names_follow_the_path(monkeypatch, op, call):
+    monkeypatch.setattr(kops, "HOST_FLOP_CUTOFF", 10 ** 12)
+    spans, up, host = _dispatch_spans(call)
+    assert [s.name for s in spans] == [f"host_op:{op}"]
+    assert host == 1 and up == 0
+    monkeypatch.setattr(kops, "HOST_FLOP_CUTOFF", 0)
+    spans, up, host = _dispatch_spans(call)
+    assert [s.name for s in spans] == [f"dispatch:{op}"]
+    assert host == 0 and up > 0
+    assert [c.name for c in spans[0].children] == ["transfer:to_device",
+                                                   "transfer:to_host"]
+
+
+def test_merge_span_follows_the_path(monkeypatch):
+    scores = np.ones((2, 3, 4), np.float32)
+    ids = np.arange(24).reshape(2, 3, 4)
+    spans, up, host = _dispatch_spans(
+        lambda: kops.merge_topk_batch(scores, ids, 5, use_pallas=True))
+    assert [s.name for s in spans] == ["dispatch:merge_topk_batch"]
+    assert up == scores.nbytes + ids.size * 4 and host == 0
+    # ids outside int32 take the exact host merge
+    spans, up, host = _dispatch_spans(
+        lambda: kops.merge_topk_batch(scores, ids + 2 ** 40, 5,
+                                      use_pallas=True))
+    assert [s.name for s in spans] == ["host_op:merge_topk_batch"]
+    assert up == 0 and host == 1
+
+
+def test_bytes_to_device_mirror_to_the_registry():
+    REGISTRY.reset()
+    kops.flush_registry_counters()
+    REGISTRY.reset()
+    before = kops.thread_stats().bytes_to_device
+    kops.l2_distances(np.ones((8, 128), np.float32),
+                      np.ones((4096, 128), np.float32), use_pallas=False)
+    kops.flush_registry_counters()
+    assert REGISTRY.get("kernels.bytes_to_device").value \
+        == kops.thread_stats().bytes_to_device - before > 0
 
 
 # ---------------------------------------------------------------------------
