@@ -711,8 +711,9 @@ class RankScore(PhysicalOp):
 class FusedScanTopK(PhysicalOp):
     """Fused masked scan -> top-k over the packed cross-segment
     superbatch (kernels/fused_scan.py).  Drains the source's per-segment
-    bitmaps, packs every surviving segment's rank column (plus bitmaps,
-    pks and row-provenance maps) into ONE bucket-padded matrix, and makes
+    bitmaps, packs every surviving segment's rank column (plus pks and
+    row-provenance maps) into ONE matrix (cached per segment set, its
+    device copy kept on the device by ``kops.fused_scan_topk``), and makes
     a single kernel dispatch for the whole query batch — only ``(nq, k)``
     distances + row ids return to the host, instead of per-segment
     ``(nq, n)`` matrices.
@@ -793,8 +794,7 @@ class FusedScanTopK(PhysicalOp):
             return [[] for _ in range(ctx.nq)]
         segs, packed, mask_all, Q = g
         k = max(qq.k for qq in ctx.queries)
-        d2, rows = kops.fused_scan_topk(Q, packed.x, mask_all,
-                                        packed.pks, k)
+        d2, rows = kops.fused_scan_topk(Q, packed, mask_all, k)
         return self._emit(ctx, segs, packed, mask_all, d2, rows,
                           scan_row_bytes=packed.x.shape[1]
                           * packed.x.dtype.itemsize)
@@ -826,8 +826,7 @@ class QuantizedScanTopK(FusedScanTopK):
         if pc is None:
             # quantized residence fell behind (mixed codebooks / missing
             # codes): exact fused scan, correctness before bandwidth
-            d2, rows = kops.fused_scan_topk(Q, packed.x, mask_all,
-                                            packed.pks, k)
+            d2, rows = kops.fused_scan_topk(Q, packed, mask_all, k)
             return self._emit(ctx, segs, packed, mask_all, d2, rows,
                               scan_row_bytes=fp_bytes)
         refine = max((getattr(p, "refine", 0) for p in ctx.plans),
@@ -842,7 +841,7 @@ class QuantizedScanTopK(FusedScanTopK):
             rr = crows[qi][crows[qi] >= 0]
             rmask[qi, rr] = True
             rerank_rows.append(len(rr))
-        d2, rows = kops.fused_scan_topk(Q, packed.x, rmask, packed.pks, k)
+        d2, rows = kops.fused_scan_topk(Q, packed, rmask, k)
         return self._emit(ctx, segs, packed, mask_all, d2, rows,
                           scan_row_bytes=pc.codes.shape[1],
                           rerank_rows=rerank_rows)
@@ -881,8 +880,7 @@ class GraphSearchTopK(FusedScanTopK):
         if pg is None:
             # graph residence fell behind (a segment without a built
             # graph): exact fused scan, correctness before traversal
-            d2, rows = kops.fused_scan_topk(Q, packed.x, mask_all,
-                                            packed.pks, k)
+            d2, rows = kops.fused_scan_topk(Q, packed, mask_all, k)
             return self._emit(ctx, segs, packed, mask_all, d2, rows,
                               scan_row_bytes=fp_bytes)
         beam = max((getattr(p, "graph_beam", 0) for p in ctx.plans),
@@ -900,7 +898,7 @@ class GraphSearchTopK(FusedScanTopK):
             rr = brows[qi][brows[qi] >= 0]
             rmask[qi, rr] = True
             rerank_rows.append(len(rr))
-        d2, rows = kops.fused_scan_topk(Q, packed.x, rmask, packed.pks, k)
+        d2, rows = kops.fused_scan_topk(Q, packed, rmask, k)
         out: List[List[Candidates]] = [[] for _ in range(ctx.nq)]
         sp = obs_trace.current_span()
         for qi, (qq, plan) in enumerate(zip(ctx.queries, ctx.plans)):

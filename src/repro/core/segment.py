@@ -14,7 +14,7 @@ import itertools
 import os
 import threading
 from collections import OrderedDict
-from typing import Any, Dict, Optional, Sequence
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -130,6 +130,22 @@ class PackedColumn:
     sids: np.ndarray         # (N,) int64 owning segment id per row
     rows: np.ndarray         # (N,) int64 row index inside the segment
     offsets: np.ndarray      # (n_segs + 1,) int64 segment start offsets
+    # the fused scan's device copy of ``x`` and ``pks``: an opaque handle
+    # that ``kernels/ops.py`` builds and reads.  An entry of
+    # ``_pack_cache`` holds it exactly as long as it stays cached.
+    device: Any = None
+    evicted: bool = False
+
+    def publish_device(self, handle: Any) -> Any:
+        """Publish ``handle`` as this column's device copy, unless another
+        thread published one first (that one wins) or the entry has left
+        ``_pack_cache`` (nothing is kept).  Returns the copy to use."""
+        with _pack_lock:
+            if self.device is not None:
+                return self.device
+            if not self.evicted:
+                self.device = handle
+        return handle
 
 
 # segments are immutable, so a packed column is valid for as long as its
@@ -142,6 +158,24 @@ _PACK_CACHE_CAP = 4
 # unguarded move_to_end/popitem pair from two threads corrupts the
 # OrderedDict's internal links
 _pack_lock = threading.Lock()
+
+
+def _publish(key: Tuple, packed: Any) -> Any:
+    """Cache ``packed`` under ``key`` and return it, or the entry another
+    thread published there first (so one device copy serves both).  Full,
+    the LRU evicts its least-recent entries; a packed column's device
+    copy goes with its entry."""
+    with _pack_lock:
+        raced = _pack_cache.get(key)
+        if raced is not None:
+            return raced
+        while len(_pack_cache) >= _PACK_CACHE_CAP:
+            _, old = _pack_cache.popitem(last=False)
+            if isinstance(old, PackedColumn):
+                old.device = None
+                old.evicted = True
+        _pack_cache[key] = packed
+    return packed
 
 
 def pack_segments(segments: Sequence[Segment], col: str) -> PackedColumn:
@@ -161,11 +195,7 @@ def pack_segments(segments: Sequence[Segment], col: str) -> PackedColumn:
                              for s, n in zip(segments, ns)]),
         rows=np.concatenate([np.arange(n, dtype=np.int64) for n in ns]),
         offsets=np.cumsum([0] + ns).astype(np.int64))
-    with _pack_lock:
-        while len(_pack_cache) >= _PACK_CACHE_CAP:
-            _pack_cache.popitem(last=False)       # evict least-recent
-        _pack_cache[key] = packed
-    return packed
+    return _publish(key, packed)
 
 
 @dataclasses.dataclass
@@ -202,11 +232,7 @@ def pack_quantized(segments: Sequence[Segment],
         codes=np.concatenate([qc.codes for qc in qcols]),
         codebooks=qcols[0].codebooks,
         book_id=book_id)
-    with _pack_lock:
-        while len(_pack_cache) >= _PACK_CACHE_CAP:
-            _pack_cache.popitem(last=False)
-        _pack_cache[key] = packed
-    return packed
+    return _publish(key, packed)
 
 
 def merge_segments(schema: Schema, segments: Sequence[Segment],

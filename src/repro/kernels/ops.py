@@ -110,12 +110,17 @@ class KernelStats:
                      jit compile-cache misses caused by ``_bucket``-padded
                      ragged inputs (the shape-cache itself is process-
                      wide, like jax's jit cache).
+    resident_hits / resident_misses — device fused scans that found their
+                     packed column's device copy already built / built it
+                     (``_resident``).
     """
     launches: int = 0
     bytes_to_host: int = 0
     shape_misses: int = 0
     host_dispatches: int = 0
     bytes_to_device: int = 0
+    resident_hits: int = 0
+    resident_misses: int = 0
     # high-water marks already published to the metrics registry; the
     # per-dispatch mirror batches (see flush_registry_counters) so the
     # hot path pays an int compare instead of a Counter lock
@@ -124,6 +129,8 @@ class KernelStats:
     reg_misses: int = 0
     reg_host: int = 0
     reg_up: int = 0
+    reg_res_hits: int = 0
+    reg_res_misses: int = 0
 
 
 _tls = threading.local()
@@ -164,7 +171,7 @@ def _registry_counters():
     """Process-wide mirrors of the per-thread counters in the metrics
     registry.  Object refs are cached (re-fetched only when
     ``REGISTRY.reset()`` bumps its generation), so the per-dispatch
-    cost is an int compare; a flush makes at most five ``Counter.inc``
+    cost is an int compare; a flush makes at most seven ``Counter.inc``
     calls."""
     global _reg_counters, _reg_generation
     if _reg_counters is None or _reg_generation != REGISTRY.generation:
@@ -173,7 +180,9 @@ def _registry_counters():
                          REGISTRY.counter("kernels.bytes_to_host"),
                          REGISTRY.counter("kernels.jit_shape_misses"),
                          REGISTRY.counter("kernels.host_dispatches"),
-                         REGISTRY.counter("kernels.bytes_to_device"))
+                         REGISTRY.counter("kernels.bytes_to_device"),
+                         REGISTRY.counter("kernels.resident_hits"),
+                         REGISTRY.counter("kernels.resident_misses"))
     return _reg_counters
 
 
@@ -186,7 +195,7 @@ def flush_registry_counters() -> None:
     query-batch boundaries (``Executor._observe_query``), keeping the
     registry's Counter lock off the per-dispatch path."""
     s = thread_stats()
-    launches, byts, misses, host, up = _registry_counters()
+    launches, byts, misses, host, up, hits, cold = _registry_counters()
     if s.launches != s.reg_launches:
         launches.inc(s.launches - s.reg_launches)
         s.reg_launches = s.launches
@@ -202,6 +211,12 @@ def flush_registry_counters() -> None:
     if s.bytes_to_device != s.reg_up:
         up.inc(s.bytes_to_device - s.reg_up)
         s.reg_up = s.bytes_to_device
+    if s.resident_hits != s.reg_res_hits:
+        hits.inc(s.resident_hits - s.reg_res_hits)
+        s.reg_res_hits = s.resident_hits
+    if s.resident_misses != s.reg_res_misses:
+        cold.inc(s.resident_misses - s.reg_res_misses)
+        s.reg_res_misses = s.resident_misses
 
 
 def _dispatched(out_bytes: int, tag: str = None, shape: Tuple = ()) -> None:
@@ -469,52 +484,57 @@ def rect_filter(points: np.ndarray, rect,
 # fused masked scan -> top-k (packed cross-segment path)
 # ---------------------------------------------------------------------------
 
-def fused_scan_topk(q: np.ndarray, x: np.ndarray, mask: np.ndarray,
-                    pks: np.ndarray, k: int,
+def fused_scan_topk(q: np.ndarray, packed, mask: np.ndarray, k: int,
                     use_pallas: bool = None
                     ) -> Tuple[np.ndarray, np.ndarray]:
-    """Fused filter-aware scan -> per-query top-k over a packed matrix.
+    """Fused filter-aware scan -> per-query top-k over a packed column.
 
-    q (nq, d) queries; x (n, d) packed vectors (all visible segments
-    concatenated); mask (nq, n) bool predicate bitmap; pks (n,) primary
-    keys (< 2^31: the device tie-break key).  Returns (d2 (nq, k) fp32
-    squared-L2 ascending, rows (nq, k) int64 row indices into ``x``; -1
-    marks slots beyond the query's candidate count).  Ties break by
-    (distance, pk) — the host merge's lexsort comparator.  The CPU
-    ``ref`` backend SIMULATES the fused kernel: it reproduces the
-    staged path's distance arithmetic at this size (numpy expansion
-    below ``HOST_FLOP_CUTOFF``, the jit'd scan above) and the host
-    merge's (sqrt-distance, pk) comparator exactly, so fused and staged
-    results are bitwise equal backend-for-backend; the Pallas kernel
-    (always, on a TPU) compares squared distances (a monotone transform
-    — same rows except where f32 sqrt rounds two distinct squared
-    distances together).
+    q (nq, d) queries; ``packed`` the ``segment.PackedColumn`` of all
+    visible segments (its ``x`` (n, d) vectors and ``pks`` (n,) primary
+    keys, < 2^31: the device tie-break key); mask (nq, n) bool predicate
+    bitmap.  Returns (d2 (nq, k) fp32 squared-L2 ascending, rows (nq, k)
+    int64 row indices into ``packed.x``; -1 marks slots beyond the
+    query's candidate count).  Ties break by (distance, pk) — the host
+    merge's lexsort comparator.  The CPU ``ref`` backend SIMULATES the
+    fused kernel: it reproduces the staged path's distance arithmetic at
+    this size (numpy expansion below ``HOST_FLOP_CUTOFF``, the jit'd scan
+    above) and the host merge's (sqrt-distance, pk) comparator exactly,
+    so fused and staged results are bitwise equal backend-for-backend;
+    the Pallas kernel (always, on a TPU) compares squared distances (a
+    monotone transform — same rows except where f32 sqrt rounds two
+    distinct squared distances together).
 
     ONE dispatch for the whole batch, whatever the segment or predicate
-    count.  Host-side prep: rows are tiled into BLOCK_N blocks; blocks
-    masked out for EVERY query (zone-map/bitmap holes) are compacted away
-    before upload, and the kept-block count is bucket-padded to a power
-    of two so ragged stores hit O(log n) jit shapes.  A per-(query-tile,
-    block) occupancy grid lets the kernel skip tiles that survive
-    compaction but are empty for this query tile.
+    count.  On the device backends the packed vectors and pks stay on
+    the device (``_resident``): the first dispatch over a packed column
+    uploads them, padded to a power-of-two count of BLOCK_N blocks so
+    ragged stores hit O(log n) jit shapes, and later dispatches over the
+    same column upload only the queries, the mask and a per-(query-tile,
+    block) occupancy grid that lets the kernel skip tiles no query of
+    the tile admits.  The ``dispatch:fused_scan_topk`` span carries
+    ``resident_hits`` / ``resident_lookups`` (1 / 1 when the copy was
+    found, 0 / 1 when this dispatch built it).
     """
     mode = backend(use_pallas)
     q = np.asarray(q, np.float32)
-    x = np.asarray(x, np.float32)
+    x = np.asarray(packed.x, np.float32)
     mask = np.asarray(mask, bool)
     nq = len(q)
     k = int(min(k, fs_kernel.KMAX))
-    empty = (np.full((nq, k), np.inf, np.float32),
-             np.full((nq, k), -1, np.int64))
     if len(x) == 0 or k == 0 or not mask.any():
-        return empty
+        return (np.full((nq, k), np.inf, np.float32),
+                np.full((nq, k), -1, np.int64))
     if mode == "ref":
         host = q.shape[0] * x.shape[0] * x.shape[1] < HOST_FLOP_CUTOFF
         with obs_trace.span("host_op:fused_scan_topk" if host
                             else "dispatch:fused_scan_topk"):
-            return _fused_scan_ref(q, x, mask, pks, k, host)
-    with obs_trace.span("dispatch:fused_scan_topk"):
-        return _fused_scan_device(q, x, mask, pks, k, mode, empty)
+            return _fused_scan_ref(q, x, mask, packed.pks, k, host)
+    found = packed.device
+    with obs_trace.span("dispatch:fused_scan_topk",
+                        resident_hits=int(found is not None),
+                        resident_lookups=1):
+        return _fused_scan_device(q, _resident(packed, found), mask, k,
+                                  mode)
 
 
 def _fused_scan_ref(q, x, mask, pks, k, host: bool):
@@ -549,46 +569,52 @@ def _fused_scan_ref(q, x, mask, pks, k, host: bool):
     return out_d, out_r
 
 
-def _fused_scan_device(q, x, mask, pks, k, mode: str, empty):
-    """The fused kernel's host prep, upload, launch and fetch (see
-    ``fused_scan_topk``)."""
-    nq = len(q)
+def _resident(packed, found) -> Tuple[jax.Array, jax.Array]:
+    """The packed column's device copy for the fused kernel: x (N, d)
+    fp32 and pks (1, N) int32, N the power-of-two count of BLOCK_N blocks
+    that holds every row; pad rows are zero with ``SENTINEL`` pks (the
+    mask never admits them).  ``found`` is the copy the caller read off
+    the column, or None: then it is built and published, and lives as
+    long as the column's ``_pack_cache`` entry
+    (``segment.PackedColumn.publish_device``)."""
+    s = thread_stats()
+    if found is not None:
+        s.resident_hits += 1
+        return found
+    s.resident_misses += 1
+    BN = fs_kernel.BLOCK_N
+    x = np.asarray(packed.x, np.float32)
+    n = len(x)
+    npad = _bucket(-(-n // BN), floor=1) * BN
+    xp = np.zeros((npad, x.shape[1]), np.float32)
+    xp[:n] = x
+    pk32 = np.full((1, npad), int(fs_kernel.SENTINEL), np.int32)
+    pk32[0, :n] = np.asarray(packed.pks, np.int64)
+    return packed.publish_device(_to_device(xp, pk32))
+
+
+def _fused_scan_device(q, resident, mask, k, mode: str):
+    """The fused kernel's host prep, upload, launch and fetch over the
+    column's device copy (see ``fused_scan_topk``)."""
+    nq, n = mask.shape
     BQ, BN = fs_kernel.BLOCK_Q, fs_kernel.BLOCK_N
-    # pad rows to a block multiple (mask=0 => padding is never selected)
-    xp = _pad_to(x, BN, 0)
-    mp = _pad_to(mask.astype(np.uint8), BN, 1)
-    pkp = _pad_to(np.asarray(pks, np.int64), BN, 0,
-                  value=int(fs_kernel.SENTINEL))
-    nb = len(xp) // BN
-    # host-side occupancy prefix: drop blocks no query can touch
-    keep = np.nonzero(mp.reshape(nq, nb, BN).any(axis=(0, 2)))[0]
-    if len(keep) == 0:
-        return empty
-    nb_pad = _bucket(len(keep), floor=1)       # blocks, not rows
-    xk = np.zeros((nb_pad * BN, x.shape[1]), np.float32)
-    mk = np.zeros((nq, nb_pad * BN), np.uint8)
-    pkk = np.full((nb_pad * BN,), int(fs_kernel.SENTINEL), np.int64)
-    xk[:len(keep) * BN] = xp.reshape(nb, BN, -1)[keep].reshape(-1,
-                                                               x.shape[1])
-    mk[:, :len(keep) * BN] = \
-        mp.reshape(nq, nb, BN)[:, keep].reshape(nq, -1)
-    pkk[:len(keep) * BN] = pkp.reshape(nb, BN)[keep].reshape(-1)
+    x_dev, pk_dev = resident
+    npad = x_dev.shape[0]
     qp = _pad_to(q, BQ, 0)
-    mkq = _pad_to(mk, BQ, 0)
-    occ = mkq.reshape(len(qp) // BQ, BQ, nb_pad, BN) \
+    # pad rows and pad queries carry mask 0: never selected
+    mp = np.zeros((len(qp), npad), np.uint8)
+    mp[:nq, :n] = mask
+    occ = mp.reshape(len(qp) // BQ, BQ, npad // BN, BN) \
         .any(axis=(1, 3)).astype(np.int32)
-    pk32 = pkk.astype(np.int32)[None, :]
+    q_dev, m_dev, occ_dev = _to_device(qp, mp, occ)
     d2, _, idx = fs_kernel.fused_scan_topk(
-        *_to_device(qp, xk, mkq, pk32, occ), k=k,
+        q_dev, x_dev, m_dev, pk_dev, occ_dev, k=k,
         interpret=mode == "interpret")
     d2, idx = _to_host(d2, idx)
     _dispatched(d2.nbytes + 2 * idx.nbytes, f"fused_scan.{mode}",
-                qp.shape + xk.shape + (k,))
+                qp.shape + tuple(x_dev.shape) + (k,))
     d2, idx = d2[:nq, :k], idx[:nq, :k]
-    # map packed block-compacted indices back to rows of the caller's x
-    safe = np.minimum(idx, len(keep) * BN - 1)
-    rows = keep[safe // BN] * BN + safe % BN
-    rows = np.where(idx == int(fs_kernel.SENTINEL), -1, rows)
+    rows = np.where(idx == int(fs_kernel.SENTINEL), -1, idx)
     return d2, rows.astype(np.int64)
 
 
@@ -772,10 +798,9 @@ def quantized_scan_topk(q: np.ndarray, codes: np.ndarray,
     final (score, pk) results match the exact dispatch whenever the
     survivors cover the true top-k.
 
-    Host-side prep mirrors ``fused_scan_topk`` exactly (pad -> keep-block
-    compaction -> power-of-two bucket -> occupancy grid), just over the
-    uint8 code matrix instead of the fp32 column: the device streams
-    m bytes/row instead of 4*d.
+    Host-side prep, every call: pad -> keep-block compaction ->
+    power-of-two bucket -> occupancy grid over the uint8 code matrix (the
+    device streams m bytes/row instead of the fused scan's 4*d).
     """
     mode = backend(use_pallas)
     q = np.asarray(q, np.float32)

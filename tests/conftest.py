@@ -43,3 +43,15 @@ def small_store():
     store.flush()
     ref = {k: np.concatenate(v) for k, v in data.items()}
     return store, ref
+
+
+def packed_column(x, pks):
+    """A ``PackedColumn`` over bare arrays, as one segment: the operand
+    ``kernels.ops.fused_scan_topk`` takes."""
+    from repro.core.segment import PackedColumn
+    n = len(x)
+    return PackedColumn(x=np.asarray(x, np.float32),
+                        pks=np.asarray(pks, np.int64),
+                        sids=np.zeros(n, np.int64),
+                        rows=np.arange(n, dtype=np.int64),
+                        offsets=np.asarray([0, n], np.int64))

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from benchmarks import tracy
+from conftest import packed_column
 from repro.core import query as q
 from repro.core import operators as ops_lib
 from repro.core.api import (Column, ColumnType, Database, IndexKind, Range,
@@ -298,32 +299,36 @@ def test_bytes_to_device_match_the_padded_operands():
     assert [c.name for c in sp.children] == ["transfer:to_device",
                                              "transfer:to_host"]
     assert sp.children[0].attrs == {"bytes": up}
-    # fused_scan_topk on the kernel path: 3 queries over 1,400 rows; the
-    # mask leaves blocks 0 and 2 of 3, compacted and bucketed to 2
+    # fused_scan_topk on the kernel path: 3 queries over 1,400 rows, 3
+    # blocks that the column's device copy pads to 4.  The first call
+    # uploads that copy (vectors, pks), then the queries, mask and
+    # occupancy; the second finds the copy and uploads only those three
     BQ, BN = fs.BLOCK_Q, fs.BLOCK_N
     q3 = rng.standard_normal((3, d)).astype(np.float32)
-    xs = rng.standard_normal((1400, d)).astype(np.float32)
+    col = packed_column(rng.standard_normal((1400, d)).astype(np.float32),
+                        np.arange(1400))
     mask = np.zeros((3, 1400), bool)
     mask[0, 5:40] = True
     mask[2, 1100:1300] = True
-    pks = np.arange(1400)
-    spans, up, host = _dispatch_spans(
-        lambda: kops.fused_scan_topk(q3, xs, mask, pks, 10,
-                                     use_pallas=True))
-    rows = 2 * BN
-    qtile = BQ                          # 3 queries pad to one tile
-    assert up == (qtile * d * 4         # queries, f32
-                  + rows * d * 4        # kept blocks of vectors, f32
-                  + qtile * rows        # mask, u8
-                  + rows * 4            # pks, i32
-                  + 1 * 2 * 4)          # occupancy (tiles x blocks), i32
-    assert host == 0
-    (sp,) = spans
-    assert sp.name == "dispatch:fused_scan_topk"
-    assert sp.children[0].attrs == {"bytes": up}
+    rows = 4 * BN
+    column = (rows * d * 4              # vectors, f32
+              + rows * 4)               # pks, i32
+    per_call = (BQ * d * 4              # queries (one tile), f32
+                + BQ * rows             # mask, u8
+                + 1 * 4 * 4)            # occupancy (tiles x blocks), i32
+    for hit, uploads in ((0, [column, per_call]), (1, [per_call])):
+        spans, up, host = _dispatch_spans(
+            lambda: kops.fused_scan_topk(q3, col, mask, 10,
+                                         use_pallas=True))
+        assert up == sum(uploads) and host == 0
+        (sp,) = spans
+        assert sp.name == "dispatch:fused_scan_topk"
+        assert sp.attrs == {"resident_hits": hit, "resident_lookups": 1}
+        assert [c.attrs for c in sp.children] == \
+            [{"bytes": b} for b in uploads] + [{}]
     # a host-path call uploads nothing
     spans, up, host = _dispatch_spans(
-        lambda: kops.l2_distances(q3, xs[:50], use_pallas=False))
+        lambda: kops.l2_distances(q3, col.x[:50], use_pallas=False))
     assert up == 0 and host == 1
     assert [s.name for s in spans] == ["host_op:l2_distances"]
     assert spans[0].children == []
@@ -340,8 +345,9 @@ def test_bytes_to_device_match_the_padded_operands():
         np.ones(8, np.float32), np.zeros((300, 4), np.uint8),
         np.ones((4, 256, 2), np.float32), use_pallas=False)),
     ("fused_scan_topk", lambda: kops.fused_scan_topk(
-        np.ones((1, 16), np.float32), np.ones((300, 16), np.float32),
-        np.ones((1, 300), bool), np.arange(300), 4, use_pallas=False)),
+        np.ones((1, 16), np.float32),
+        packed_column(np.ones((300, 16), np.float32), np.arange(300)),
+        np.ones((1, 300), bool), 4, use_pallas=False)),
 ])
 def test_dispatch_span_names_follow_the_path(monkeypatch, op, call):
     monkeypatch.setattr(kops, "HOST_FLOP_CUTOFF", 10 ** 12)
