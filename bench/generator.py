@@ -5,16 +5,32 @@ a closed loop with one client.
 Traffic runs in blocks.  A block holds ``queries_per_block`` queries and
 ``writes_per_block`` writes in an order drawn from the seed.  Queries
 take the listed templates in turn from a fresh seeded permutation each
-round, so every seed sends the same mix in another order; each query
-draws its parameters from the seed.  A write inserts ``write.insert`` new
+round, so every seed sends the same mix in another order, or, with
+``"template_order": "listed"``, in the listed order, so that every seed
+spends its window on the same templates; each query draws its
+parameters from the seed.  A write inserts ``write.insert`` new
 rows (pks counting up), overwrites ``write.update`` live rows and deletes
 ``write.delete`` live rows, drawn uniformly from the rows live when the
-write is made.
+write is made.  After a block's queries and writes come
+``advances_per_block`` advances of the table's clock, each
+``clock_step_s`` past the last, so that a refresh follows the writes it
+must reflect.
+
+``subscriptions`` (``{"templates", "sync", "async", "interval_s"}``)
+lists the standing queries a cell registers at set-up: ``sync`` of them
+refreshed every ``interval_s`` of the clock and ``async`` on every
+change, taking the templates in turn, their parameters drawn from one
+fixed stream apart from the queries': every seed registers the same
+ranges, regions and topic offsets (the vectors still sit around the
+run's own topic centres), so that the views chosen, and the work of a
+refresh, do not follow the seed.  A key that a traffic file leaves out
+draws nothing.
 
 Ops are plain tuples:
 
   ("query", template, spec)
   ("write", insert_pks, insert_batch, update_pks, update_batch, delete_pks)
+  ("advance", now)
 """
 from __future__ import annotations
 
@@ -23,6 +39,9 @@ from typing import Dict, Iterator, List, Tuple
 import numpy as np
 
 from bench.data import tracy
+
+# the subscriptions' own stream: registering them moves no query
+STREAM_SUBSCRIPTIONS = 4
 
 
 class Generator:
@@ -40,8 +59,13 @@ class Generator:
         if unknown:
             raise KeyError(f"unknown templates {sorted(unknown)}")
         self._turn: List[str] = []
+        order = traffic.get("template_order", "seeded")
+        if order not in ("seeded", "listed"):
+            raise ValueError(f"unknown template_order {order!r}")
+        self._listed = order == "listed"
         self.next_pk = 0
         self.live = np.zeros(1 << 16, bool)
+        self.clock = 0.0
 
     # ------------------------------------------------------------- rows
     def _new_pks(self, n: int) -> np.ndarray:
@@ -64,10 +88,28 @@ class Generator:
     # ------------------------------------------------------------ queries
     def query(self) -> Tuple[str, Dict]:
         if not self._turn:
-            self._turn = [self._names[i] for i in
-                          self.ops_rng.permutation(len(self._names))]
+            self._turn = self._names[::-1] if self._listed else [
+                self._names[i] for i in
+                self.ops_rng.permutation(len(self._names))]
         name = self._turn.pop()
         return name, self._templates[name]()
+
+    def subscriptions(self) -> List[Tuple[str, str, float, Dict]]:
+        """The standing queries, as (template, "sync"|"async",
+        interval_s, spec): the SYNC ones first, templates in turn."""
+        s = self.traffic.get("subscriptions")
+        if not s:
+            return []
+        data = tracy.TracyData(0, STREAM_SUBSCRIPTIONS,
+                               self.rows.topic_centers)
+        templates = tracy.make_templates(data)
+        kinds = ["sync"] * s["sync"] + ["async"] * s["async"]
+        out = []
+        for i, kind in enumerate(kinds):
+            name = s["templates"][i % len(s["templates"])]
+            out.append((name, kind, float(s["interval_s"]),
+                        templates[name]()))
+        return out
 
     # ------------------------------------------------------------- writes
     def write(self) -> Tuple:
@@ -95,6 +137,9 @@ class Generator:
                 ops.append(("query",) + self.query())
             else:
                 ops.append(self.write())
+        for _ in range(t.get("advances_per_block", 0)):
+            self.clock += t["clock_step_s"]
+            ops.append(("advance", self.clock))
         return ops
 
     def ops(self) -> Iterator[Tuple]:

@@ -1,7 +1,7 @@
 """Kernel dispatch layer (kernels/ops.py): self time of the program's
 ``dispatch:<op>`` spans per query, in ms: the host's prep for a device
-launch (padding, block compaction, masks, occupancy) and the launch
-call, without the transfers, which are spans of their own."""
+launch (padding, masks, occupancy) and the launch call, without the
+transfers, which are spans of their own."""
 
 
 def read(rec):

@@ -34,16 +34,35 @@ def schema(cfg: Dict[str, Any]):
         for c in cfg["schema"]])
 
 
+TABLE_KEYS = ("continuous_mode", "view_budget_bytes")
+
+
 def open_table(cfg: Dict[str, Any], path: str):
     """A durable ``Database`` at ``path`` with the configuration's
-    ``LSMConfig``; returns (db, table)."""
+    ``LSMConfig`` and its optional ``table`` settings (the continuous
+    engine's ``continuous_mode`` and ``view_budget_bytes``); returns
+    (db, table)."""
     from repro.core.api import Database, LSMConfig
     fields = {f.name for f in dataclasses.fields(LSMConfig)}
     unknown = set(cfg["lsm"]) - fields
     if unknown:
         raise KeyError(f"LSMConfig has no {sorted(unknown)}")
-    db = Database(schema(cfg), LSMConfig(**cfg["lsm"]), path=path)
+    table = cfg.get("table", {})
+    unknown = set(table) - set(TABLE_KEYS)
+    if unknown:
+        raise KeyError(f"Database takes no table setting {sorted(unknown)}")
+    db = Database(schema(cfg), LSMConfig(**cfg["lsm"]), path=path, **table)
     return db, db.table()
+
+
+def subscribe(table, spec, kind: str, interval_s: float):
+    """Register ``spec`` as a standing query: ``"sync"`` refreshes every
+    ``interval_s`` of the table's clock, ``"async"`` on every change."""
+    if kind == "sync":
+        return table.subscribe(to_query(spec), interval_s=interval_s)
+    if kind == "async":
+        return table.subscribe(to_query(spec), on_change=True)
+    raise ValueError(f"unknown subscription kind {kind!r}")
 
 
 def _expr(p):

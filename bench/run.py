@@ -8,12 +8,16 @@ and the limits of the comparison) and a traffic file
 (``bench/traffic/<traffic>.json``, read by ``bench/generator.py``).
 
 Set-up, from process start: the durable store built through the
-``Database`` facade from ``--seed``, and ``warmup_blocks`` blocks of the
-cell's own traffic, which compile or load from JAX's persistent cache
-every program the window uses.  The window then drives the facade
-(``put``, ``delete``, ``execute``) in a closed loop for ``--seconds``.  After it the device's peak memory is
+``Database`` facade from ``--seed``, the traffic's standing subscriptions
+registered on it (view selection and backfill included), and
+``warmup_blocks`` blocks of the cell's own traffic, which compile or load
+from JAX's persistent cache every program the window uses.  The window
+then drives the facade (``put``, ``delete``, ``execute``, ``advance``) in
+a closed loop for ``--seconds``.  After it the device's peak memory is
 read, the store is closed, and every answer the window produced is
-compared with the float64 reference in ``bench/reference.py``.
+compared with the float64 reference in ``bench/reference.py``: each
+query's, each SYNC refresh an advance returned, and every ASYNC
+subscription's ``latest`` after each advance.
 
 With ``--trace 0`` the result's metrics are the cell's end-to-end ones;
 with ``--trace 1`` the window also runs under the JAX profiler and the
@@ -180,6 +184,7 @@ class Run:
         self.gen = Generator(self.traffic, self.config, self.seed)
         self.path = root / self.config["store_path"] / self.cell["name"]
         self.log: List[tuple] = []       # op log replayed by the reference
+        self.subs: List[tuple] = []      # (label, kind, spec, subscription)
         self.info: Dict[str, Any] = {}
 
     # ------------------------------------------------------------ set-up
@@ -193,7 +198,14 @@ class Run:
             self.log.append(("write", pks, batch))
         self.table.flush()
         t1 = time.perf_counter()
-        flush0 = self.table.store.metrics["flush_s"]
+        for i, (name, kind, interval, spec) in enumerate(
+                self.gen.subscriptions()):
+            self.subs.append((f"sub {name}#{i} {kind}", kind, spec,
+                              program.subscribe(self.table, spec, kind,
+                                                interval)))
+        t2 = time.perf_counter()
+        store = self.table.store.metrics
+        flush0 = (store["flush_s"], store["flushes"], store["compactions"])
         warm: Dict[str, List[float]] = {}
         for _ in range(self.traffic["warmup_blocks"]):
             for op in self.gen.block():
@@ -202,8 +214,11 @@ class Run:
                 key = op[1] if op[0] == "query" else op[0]
                 warm.setdefault(key, []).append(time.perf_counter() - t)
         self.info.update(
-            preload_s=t1 - t0, warmup_s=time.perf_counter() - t1,
-            warmup_flush_s=self.table.store.metrics["flush_s"] - flush0,
+            preload_s=t1 - t0, subscribe_s=t2 - t1,
+            warmup_s=time.perf_counter() - t2,
+            warmup_flush_s=store["flush_s"] - flush0[0],
+            warmup_flushes=store["flushes"] - flush0[1],
+            warmup_compactions=store["compactions"] - flush0[2],
             warmup_ops={k: [len(v), sum(v), max(v)]
                          for k, v in sorted(warm.items())},
             rows=self.table.n_rows,
@@ -232,14 +247,34 @@ class Run:
             self.log += [("write", ins, ins_b), ("write", upd, upd_b),
                          ("delete", dele)]
             return ("write", dt)
+        if kind == "advance":
+            t0 = time.perf_counter()
+            due = self.table.advance(op[1])
+            dt = time.perf_counter() - t0
+            if record:
+                self.log_subscriptions(due)
+            return ("advance", dt)
         raise ValueError(f"unknown op {kind!r}")
+
+    def log_subscriptions(self, due: Dict[int, Any]) -> None:
+        """Log what the subscribers see after an advance: each SYNC
+        refresh it returned, exact at this point of the log, and every
+        ASYNC subscription's ``latest``, which has to follow every
+        acknowledged write."""
+        for what, kind, spec, sub in self.subs:
+            got = due.get(sub.rid)
+            if kind == "async":
+                got = [] if sub.latest is None else sub.latest
+            if got is not None:
+                self.log.append(("answer", spec, got, what))
 
     # ------------------------------------------------------------- window
     def window(self) -> Dict[str, Any]:
         import jax
-        lat: Dict[str, List[float]] = {"query": [], "write": []}
+        lat: Dict[str, List[float]] = {"query": [], "write": [],
+                                       "advance": []}
         by_template: Dict[str, List[float]] = {}
-        attempted = failed = 0
+        attempted = failed = rows_acked = 0
         spans = SpanTotals()
         before = program.counters(self.table)
         c0 = Compiles.snapshot()
@@ -269,6 +304,8 @@ class Run:
                         + traceback.format_exc())
                     continue
                 lat[kind].append(dt)
+                if kind == "write":
+                    rows_acked += len(op[1]) + len(op[3]) + len(op[5])
                 if label:
                     by_template.setdefault(label, []).append(dt)
                 if self.trace:
@@ -278,6 +315,7 @@ class Run:
         c1 = Compiles.snapshot()
         out = {"window_s": window_s, "lat": lat, "by_template": by_template,
                "attempted": attempted, "failed": failed,
+               "rows_acked": rows_acked,
                "counters": {"before": before, "after": after},
                "compiles": {k: c1[k] - c0[k] for k in c0},
                "spans": spans.totals()}
@@ -339,18 +377,29 @@ def percentile(values: List[float], q: float) -> float:
 
 
 def end_to_end(w: Dict[str, Any], setup_s: float) -> Dict[str, float]:
-    """Every end-to-end number this window can give."""
+    """Every end-to-end number this window can give.  The ingest rate is
+    the rows the window's writes acknowledged (inserted, updated and
+    deleted) over the time those writes took, inline flush, compaction
+    and view maintenance included: in a closed loop the window's length
+    would fold the queries' speed into it."""
     out = {"setup_s": setup_s}
     q = w["lat"]["query"]
     if q:
         out["query_p50_ms"] = statistics.median(q) * 1e3
         out["query_p95_ms"] = percentile(q, 95) * 1e3
+    if w["lat"]["write"]:
+        out["ingest_rows_per_s"] = w["rows_acked"] / sum(w["lat"]["write"])
+    if w["lat"]["advance"]:
+        out["cq_refresh_p50_ms"] = statistics.median(w["lat"]["advance"]) \
+            * 1e3
     return out
 
 
 def records(w: Dict[str, Any], device: Optional[Dict]) -> Dict[str, Any]:
     """What the per-layer readers read."""
     return {"queries": len(w["lat"]["query"]),
+            "rows_acked": w["rows_acked"],
+            "advances": len(w["lat"]["advance"]),
             "by_template": w["by_template"],
             "window_s": w["window_s"],
             "counters": w["counters"],
@@ -438,6 +487,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                "setup_compiles": c_setup, "window_compiles": w["compiles"],
                "window_s": w["window_s"],
                "ops": {k: len(v) for k, v in w["lat"].items()},
+               "rows_acked": w["rows_acked"],
+               "write_s": sum(w["lat"]["write"]),
                "query_ms_by_template": {
                    t: [len(v), statistics.median(v) * 1e3]
                    for t, v in sorted(w["by_template"].items())},
