@@ -6,10 +6,11 @@ The program computes its distances in float32 at ``Precision.HIGHEST``;
 the step below is ``high``: three bf16 passes (``x_hi q_hi + x_hi q_lo +
 x_lo q_hi``), here written out in numpy so that it means the same on
 every machine.  The control answers the cell's own traffic, drawn from
-each seed as a run draws it (the preload, then blocks of queries and
-writes), and the same verdict as a run (``bench/reference.py``, with the
-configuration's limits) must find it not correct: its readings are the
-upper end of each limit in the configuration file.
+each seed as a run draws it (the preload, then blocks of queries, writes
+and advances of the clock), and the same verdict as a run
+(``bench/reference.py``, with the configuration's limits) must find it
+not correct: its readings are the upper end of each limit in the
+configuration file.
 
     python3 -m bench.control --workload tracy.read-fused --seeds 1,2,3
 
@@ -92,11 +93,16 @@ class Control(ref_lib.Reference):
 
 
 def read_seed(workload: str, seed: int, blocks: int,
-              overrides: Optional[Dict] = None) -> Dict:
+              overrides: Optional[Dict] = None,
+              bench: Optional[Dict] = None) -> Dict:
     """The control over ``blocks`` blocks of the cell's traffic from
     ``seed``, after its preload: every write applied to both sides, every
-    query answered by the control and judged by the run's own verdict."""
-    found = find_cell(workload)
+    query answered by the control and judged by the run's own verdict.
+    After each advance it answers the standing subscriptions as a run
+    logs them: the SYNC ones that are due (every ``interval_s`` of the
+    clock, from the first advance) and every ASYNC one.  ``bench`` stands
+    in for ``BENCHMARK.json``."""
+    found = find_cell(workload, bench=bench)
     for part, values in (overrides or {}).items():
         found[part].update(values)
     config = found["config"]
@@ -106,6 +112,8 @@ def read_seed(workload: str, seed: int, blocks: int,
     for pks, batch in gen.preload():
         ref.write(pks, batch)
         low.write(pks, batch)
+    subs = gen.subscriptions()
+    due = [0.0] * len(subs)
     tally = ref_lib.Tally()
     t0 = time.perf_counter()
     for b in range(blocks):
@@ -113,6 +121,17 @@ def read_seed(workload: str, seed: int, blocks: int,
             if op[0] == "query":
                 ref_lib.compare(ref, op[2], ref_lib.top_k_answer(low, op[2]),
                                 tally, f"control block {b} {op[1]}")
+                continue
+            if op[0] == "advance":
+                for i, (name, kind, interval, spec) in enumerate(subs):
+                    if kind == "sync":
+                        if op[1] < due[i]:
+                            continue
+                        due[i] = op[1] + interval
+                    ref_lib.compare(ref, spec,
+                                    ref_lib.top_k_answer(low, spec), tally,
+                                    f"control block {b} sub {name}#{i} "
+                                    f"{kind}")
                 continue
             _, ins, ins_b, upd, upd_b, dele = op
             for side in (ref, low):
