@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+from collections.abc import Mapping
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -71,7 +72,34 @@ class ExecStats:
 class ResultRow:
     pk: int
     score: float
-    values: Dict[str, Any]
+    values: Mapping[str, Any]
+
+
+class RowValues(Mapping):
+    """One answer row's selected columns, read in place: ``cols`` maps
+    each selected name to its source's whole column (an immutable
+    segment's, or the memtable snapshot the query ran on), and ``row`` is
+    the row's index in that source.  Reads return what indexing the
+    column gives: a NumPy scalar, a row of a vector or point column, the
+    stored text object."""
+
+    __slots__ = ("_cols", "_row")
+
+    def __init__(self, cols: Dict[str, np.ndarray], row: int):
+        self._cols = cols
+        self._row = row
+
+    def __getitem__(self, name: str) -> Any:
+        return self._cols[name][self._row]
+
+    def __iter__(self) -> Iterator[str]:
+        return iter(self._cols)
+
+    def __len__(self) -> int:
+        return len(self._cols)
+
+    def __repr__(self) -> str:
+        return repr(dict(self))
 
 
 # ---------------------------------------------------------------------------
@@ -1042,9 +1070,9 @@ class ShardConcat(PhysicalOp):
 # materialization
 # ---------------------------------------------------------------------------
 
-def candidate_pks(ctx: PipelineContext, c: Candidates) -> np.ndarray:
+def candidate_pks(ctx: PipelineContext, c: Candidates,
+                  seg_by_id: Dict[int, Any]) -> np.ndarray:
     pks = np.empty(len(c.sids), np.int64)
-    seg_by_id = {s.seg_id: s for s in ctx.segments}
     for sid in np.unique(c.sids):
         sel = c.sids == sid
         if sid < 0:
@@ -1057,26 +1085,33 @@ def candidate_pks(ctx: PipelineContext, c: Candidates) -> np.ndarray:
 
 def materialize(ctx: PipelineContext, query, c: Candidates,
                 k: Optional[int] = None) -> List[ResultRow]:
-    """Sort candidates by (score, pk), optionally cut to k, and gather the
-    selected columns for the surviving rows only."""
-    pks = candidate_pks(ctx, c)
-    order = np.lexsort((pks, c.scores))
-    if k is not None:
-        order = order[:k]
-    select = query.select or [col.name for col in ctx.store.schema.columns]
-    seg_by_id = {s.seg_id: s for s in ctx.segments}
-    out: List[ResultRow] = []
-    for t in order:
-        sid, row = int(c.sids[t]), int(c.rows[t])
-        if sid < 0:
-            _, _, _, cols = ctx.memtable_arrays()
-            values = {name: cols[name][row] for name in select}
-        else:
-            seg = seg_by_id[sid]
-            values = {name: seg.columns[name][row] for name in select}
-        out.append(ResultRow(pk=int(pks[t]), score=float(c.scores[t]),
-                             values=values))
-    return out
+    """Sort candidates by (score, pk), optionally cut to k, and build the
+    answer column by column: one set of selected columns per source
+    (segment or memtable snapshot), each returned row a ``RowValues``
+    pointing into its source's set, with pks and scores taken as Python
+    numbers in one conversion each."""
+    with obs_trace.span("result:materialize") as sp:
+        select = query.select or [col.name
+                                  for col in ctx.store.schema.columns]
+        seg_by_id = {s.seg_id: s for s in ctx.segments}
+        pks = candidate_pks(ctx, c, seg_by_id)
+        order = np.lexsort((pks, c.scores))
+        if k is not None:
+            order = order[:k]
+        sids, rows = c.sids[order], c.rows[order]
+        uniq, src = np.unique(sids, return_inverse=True)
+        sources = []
+        for sid in uniq.tolist():
+            cols = ctx.memtable_arrays()[3] if sid < 0 \
+                else seg_by_id[sid].columns
+            sources.append({name: cols[name] for name in select})
+        row_cols = [sources[i] for i in src.tolist()]
+        out = list(map(ResultRow, pks[order].tolist(),
+                       c.scores[order].tolist(),
+                       map(RowValues, row_cols, rows.tolist())))
+        if sp.live:
+            sp.set(rows=len(out), columns=len(select))
+        return out
 
 
 # ---------------------------------------------------------------------------
